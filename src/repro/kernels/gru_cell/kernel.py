@@ -33,18 +33,22 @@ def _seq_kernel(*refs, block_t: int, T: int, masked: bool,
     """One grid step = one T-block of one recurrence ``g``.
 
     Grid is (G, n_t) with t innermost; h persists in VMEM scratch across
-    the t walk and is re-seeded from h0 at each cell's first block.
+    the t walk and is re-seeded from h0 at each cell's first block.  The
+    operand layout is the LSTM twin's (kernels.lstm_cell.kernel): gates
+    flattened into the lanes (U (Hr, 3H), xw stripe (bt, B, 3H)
+    time-major), each gate a static lane slice, the step's h collected in
+    the f32 ``ys_scr`` stripe and written to ``hs_ref`` once per block.
 
     ``masked``: a per-row validity mask (ragged-B packing) rides along as
-    an extra input; padded rows freeze their state exactly like the T-edge
-    mask, so they are exact no-ops.
+    an extra (B, 1) input; padded rows freeze their state exactly like the
+    T-edge mask, so they are exact no-ops.
 
     ``quant`` / ``sparse``: the int8 per-gate and row-compacted U paths —
     see the LSTM twin in kernels.lstm_cell.kernel.  The GRU subtlety: the
-    per-gate scale must multiply the full (B, 3, H) recurrent accumulate
-    BEFORE the reset gate couples ``r * hu[:, 2]`` into the candidate, so
-    the dequantized value the gates see matches the oracle's
-    ``h @ (Uq * s)`` up to dot/scale distributivity.
+    per-gate scale must multiply the full (B, 3H) recurrent accumulate
+    BEFORE the reset gate couples ``r * hu_n`` into the candidate, so the
+    dequantized value the gates see matches the oracle's ``h @ (Uq * s)``
+    up to dot/scale distributivity.
     """
     refs = list(refs)
     xw_ref, u_ref = refs[:2]
@@ -58,53 +62,43 @@ def _seq_kernel(*refs, block_t: int, T: int, masked: bool,
     pos += 1
     if masked:
         m_ref, pos = refs[pos], pos + 1
-    hs_ref, hn_ref, h_scr = refs[pos:]
+    hs_ref, hn_ref, h_scr, ys_scr = refs[pos:]
     t = pl.program_id(1)
 
     @pl.when(t == 0)
     def _seed():
         h_scr[...] = h0_ref[0].astype(jnp.float32)
 
-    U = u_ref[0]                 # (Hr, 3, H) — resident across the walk
-    Hr, H = U.shape[0], U.shape[2]
-    U2 = U.reshape(Hr, 3 * H)
-    if quant:
-        # scale-free int8 -> f32 upcast ONCE per grid step, outside the
-        # t loop; the per-gate scale rides on the accumulate below
-        U2 = U2.astype(jnp.float32)
-    xw_blk = xw_ref[0]                # (B, block_t, 3, H) — streamed stripe
-    B = xw_blk.shape[0]
+    # (Hr, 3H) — resident across the walk; upcast ONCE per grid step
+    U = u_ref[0].astype(jnp.float32)
+    H = hs_ref.shape[-1]
     base = t * block_t
+    row_ok = None if m_ref is None else m_ref[0] != 0        # (B, 1)
 
-    def step(i, carry):
-        h, ys = carry
-        xw_t = jax.lax.dynamic_index_in_dim(xw_blk, i, axis=1,
-                                            keepdims=False)  # (B, 3, H)
-        h_in = h if not sparse else jnp.take(h, rows_ref[0], axis=1)
+    def step(i, h):
+        h_in = h if not sparse else jnp.take(h, rows_ref[0, 0], axis=1)
         hu = jax.lax.dot_general(
-            h_in, U2, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).reshape(B, 3, H)
+            h_in, U, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # (B, 3H)
         if quant:
-            hu = hu * s_ref[0][None, :, None]
-        xw32 = xw_t.astype(jnp.float32)
-        z = jax.nn.sigmoid(xw32[:, 0] + hu[:, 0])
-        r = jax.nn.sigmoid(xw32[:, 1] + hu[:, 1])
-        n = jnp.tanh(xw32[:, 2] + r * hu[:, 2])
+            hu = hu * s_ref[0]
+        xw32 = xw_ref[0, i].astype(jnp.float32)
+        z = jax.nn.sigmoid(xw32[:, 0 * H:1 * H] + hu[:, 0 * H:1 * H])
+        r = jax.nn.sigmoid(xw32[:, 1 * H:2 * H] + hu[:, 1 * H:2 * H])
+        n = jnp.tanh(xw32[:, 2 * H:3 * H] + r * hu[:, 2 * H:3 * H])
         h_new = (1 - z) * n + z * h
         # T-edge mask: the last block's tail reads BlockSpec padding
         # (undefined, NaN under interpret) — freeze the state there
         valid = base + i < T
-        if m_ref is not None:
-            valid = jnp.logical_and(valid, m_ref[0] != 0)[:, None]  # (B, 1)
+        if row_ok is not None:
+            valid = jnp.logical_and(valid, row_ok)
         h = jnp.where(valid, h_new, h)
-        ys = jax.lax.dynamic_update_index_in_dim(ys, h, i, axis=1)
-        return h, ys
+        ys_scr[i] = h
+        return h
 
-    ys0 = jnp.zeros((B, block_t, H), jnp.float32)
-    h, ys = jax.lax.fori_loop(0, block_t, step, (h_scr[...], ys0))
+    h = jax.lax.fori_loop(0, block_t, step, h_scr[...])
     h_scr[...] = h
-    hs_ref[0] = ys.astype(hs_ref.dtype)
+    hs_ref[0] = ys_scr[...].astype(hs_ref.dtype)
     hn_ref[0] = h.astype(hn_ref.dtype)
 
 
@@ -112,7 +106,7 @@ def gru_seq_pallas(U3, xw, h0, *, block_t: int, interpret: bool = True,
                    b_mask=None, u_scales=None, u_rows=None):
     """Sequence-fused GRU recurrence — ONE kernel launch for all T steps.
 
-    U3 (G,H,3,H); xw (G,B,T,3,H) precomputed input half (+bias);
+    U3 (G,Hr,3,H); xw (G,B,T,3,H) precomputed input half (+bias);
     h0 (G,B,H).  Returns (hs (G,B,T,H), h_T (G,B,H)).  ``G`` batches
     independent recurrences (e.g. the GRU cells of one wavefront slot);
     pass G=1 for a single layer.  ``b_mask`` (G,B) int32 marks valid batch
@@ -120,6 +114,7 @@ def gru_seq_pallas(U3, xw, h0, *, block_t: int, interpret: bool = True,
 
     ``u_scales`` (G,3) f32: U3 is int8 per-gate quantized; ``u_rows``
     (G,Ha) int32: U3 is row-compacted to (G,Ha,3,H) (see kernels.quant).
+    Operands are relaid out for the TPU tiling as in ``lstm_seq_pallas``.
     """
     G, B, T, _, H = xw.shape
     Hr = U3.shape[1]
@@ -131,41 +126,43 @@ def gru_seq_pallas(U3, xw, h0, *, block_t: int, interpret: bool = True,
     sparse = u_rows is not None
     kernel = functools.partial(_seq_kernel, block_t=bt, T=T, masked=masked,
                                quant=quant, sparse=sparse)
+    xw_tm = jnp.swapaxes(xw.reshape(G, B, T, 3 * H), 1, 2)   # (G,T,B,3H)
     in_specs = [
-        pl.BlockSpec((1, B, bt, 3, H), lambda g, t: (g, 0, t, 0, 0)),  # xw
-        pl.BlockSpec((1, Hr, 3, H), lambda g, t: (g, 0, 0, 0)),        # U3
+        pl.BlockSpec((1, bt, B, 3 * H), lambda g, t: (g, t, 0, 0)),  # xw
+        pl.BlockSpec((1, Hr, 3 * H), lambda g, t: (g, 0, 0)),        # U
     ]
-    args = (xw, U3)
+    args = (xw_tm, U3.reshape(G, Hr, 3 * H))
     if quant:
-        in_specs.append(pl.BlockSpec((1, 3), lambda g, t: (g, 0)))     # scales
-        args += (u_scales,)
+        in_specs.append(pl.BlockSpec((1, 1, 3 * H), lambda g, t: (g, 0, 0)))
+        args += (jnp.repeat(u_scales, H, axis=-1)[:, None],)     # scales
     if sparse:
         Ha = u_rows.shape[1]
-        in_specs.append(pl.BlockSpec((1, Ha), lambda g, t: (g, 0)))    # rows
-        args += (u_rows,)
+        in_specs.append(pl.BlockSpec((1, 1, Ha), lambda g, t: (g, 0, 0)))
+        args += (u_rows[:, None],)                               # rows
     in_specs.append(pl.BlockSpec((1, B, H), lambda g, t: (g, 0, 0)))   # h0
     args += (h0,)
     if masked:
-        in_specs.append(pl.BlockSpec((1, B), lambda g, t: (g, 0)))     # mask
-        args += (b_mask,)
+        in_specs.append(pl.BlockSpec((1, B, 1), lambda g, t: (g, 0, 0)))
+        args += (b_mask[:, :, None],)                            # mask
     hs, h_n = pl.pallas_call(
         kernel,
         grid=(G, n_t),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, B, bt, H), lambda g, t: (g, 0, t, 0)),        # hs
-            pl.BlockSpec((1, B, H), lambda g, t: (g, 0, 0)),               # h_T
+            pl.BlockSpec((1, bt, B, H), lambda g, t: (g, t, 0, 0)),    # hs
+            pl.BlockSpec((1, B, H), lambda g, t: (g, 0, 0)),           # h_T
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((G, B, T, H), h0.dtype),
+            jax.ShapeDtypeStruct((G, T, B, H), h0.dtype),
             jax.ShapeDtypeStruct((G, B, H), h0.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((B, H), jnp.float32),   # h — resident across t
+            pltpu.VMEM((B, H), jnp.float32),       # h — resident across t
+            pltpu.VMEM((bt, B, H), jnp.float32),   # this block's h stripe
         ],
         interpret=interpret,
     )(*args)
-    return hs, h_n
+    return jnp.swapaxes(hs, 1, 2), h_n
 
 
 # ===========================================================================
@@ -179,10 +176,10 @@ def _decode_kernel(xw0_ref, w_ref, b_ref, u_ref, h0_ref, hn_ref, y_scr,
     twin in kernels.lstm_cell.kernel for the full story): the layer chain
     serializes through ``y_scr``, layer 0 uses the pre-hoisted ``xw0``
     (its in-kernel input GEMM pl.when-guarded away), deeper layers compute
-    their input GEMM in-kernel — one launch per tick instead of L."""
+    their input GEMM in-kernel — one launch per tick instead of L.  Gates
+    are flattened into the lanes ((B, 3H), weights (H, 3H))."""
     l = pl.program_id(0)
-    H = u_ref.shape[-1]
-    B = xw0_ref.shape[0]
+    H = hn_ref.shape[-1]
 
     @pl.when(l == 0)
     def _first():
@@ -194,22 +191,23 @@ def _decode_kernel(xw0_ref, w_ref, b_ref, u_ref, h0_ref, hn_ref, y_scr,
         # (``xw_dtype``) — see the LSTM twin for why this keeps
         # low-precision weight stacks bit-identical too
         xw = jax.lax.dot_general(
-            y_scr[...], w_ref[0].reshape(H, 3 * H).astype(jnp.float32),
+            y_scr[...], w_ref[0].astype(jnp.float32),
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ).astype(xw_dtype).reshape(B, 3, H)
+        ).astype(xw_dtype)
         xw_scr[...] = (xw + b_ref[0].astype(xw_dtype)).astype(jnp.float32)
 
     xw = xw_scr[...]
+    h0 = h0_ref[0].astype(jnp.float32)
     hu = jax.lax.dot_general(
-        h0_ref[0].astype(jnp.float32), u_ref[0].reshape(H, 3 * H),
+        h0, u_ref[0].astype(jnp.float32),
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ).reshape(B, 3, H)
-    z = jax.nn.sigmoid(xw[:, 0] + hu[:, 0])
-    r = jax.nn.sigmoid(xw[:, 1] + hu[:, 1])
-    n = jnp.tanh(xw[:, 2] + r * hu[:, 2])
-    h = (1 - z) * n + z * h0_ref[0].astype(jnp.float32)
+    )
+    z = jax.nn.sigmoid(xw[:, 0 * H:1 * H] + hu[:, 0 * H:1 * H])
+    r = jax.nn.sigmoid(xw[:, 1 * H:2 * H] + hu[:, 1 * H:2 * H])
+    n = jnp.tanh(xw[:, 2 * H:3 * H] + r * hu[:, 2 * H:3 * H])
+    h = (1 - z) * n + z * h0
     y_scr[...] = h.astype(out_dtype).astype(jnp.float32)
     hn_ref[0] = h.astype(hn_ref.dtype)
 
@@ -229,10 +227,10 @@ def gru_decode_pallas(xw0, Ws, bs, Us, h0, *, interpret: bool = True):
         kernel,
         grid=(L,),
         in_specs=[
-            pl.BlockSpec((B, 3, H), lambda l: (0, 0, 0)),        # xw0
-            pl.BlockSpec((1, H, 3, H), lambda l: (l, 0, 0, 0)),  # Ws
-            pl.BlockSpec((1, 3, H), lambda l: (l, 0, 0)),        # bs
-            pl.BlockSpec((1, H, 3, H), lambda l: (l, 0, 0, 0)),  # Us
+            pl.BlockSpec((B, 3 * H), lambda l: (0, 0)),          # xw0
+            pl.BlockSpec((1, H, 3 * H), lambda l: (l, 0, 0)),    # Ws
+            pl.BlockSpec((1, 1, 3 * H), lambda l: (l, 0, 0)),    # bs
+            pl.BlockSpec((1, H, 3 * H), lambda l: (l, 0, 0)),    # Us
             pl.BlockSpec((1, B, H), lambda l: (l, 0, 0)),        # h0
         ],
         out_specs=[
@@ -242,9 +240,10 @@ def gru_decode_pallas(xw0, Ws, bs, Us, h0, *, interpret: bool = True):
             jax.ShapeDtypeStruct((L, B, H), h0.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((B, H), jnp.float32),     # y — the layer chain's wire
-            pltpu.VMEM((B, 3, H), jnp.float32),  # xw — this layer's input half
+            pltpu.VMEM((B, H), jnp.float32),       # y — the layer chain's wire
+            pltpu.VMEM((B, 3 * H), jnp.float32),   # xw — this layer's input half
         ],
         interpret=interpret,
-    )(xw0, Ws, bs, Us, h0)
+    )(xw0.reshape(B, 3 * H), Ws.reshape(L, H, 3 * H),
+      bs.reshape(L, 1, 3 * H), Us.reshape(L, H, 3 * H), h0)
     return h_n

@@ -117,19 +117,27 @@ def _seq_kernel(*refs, block_t: int, T: int, masked: bool,
     Grid is (G, n_t) with t innermost; (h, c) persist in VMEM scratch across
     the t walk and are re-seeded from (h0, c0) at each cell's first block.
 
+    Layout (see ``lstm_seq_pallas``): the gate axis is flattened into the
+    lanes — U (Hr, 4H), the xw stripe (bt, B, 4H) time-major — so a step
+    reads its (B, 4H) input row by a leading-axis ref index and every gate
+    is a static lane slice ``[k·H, (k+1)·H)``; no in-kernel reshape.  The
+    step's h goes to the f32 ``ys_scr`` (bt, B, H) stripe, written to
+    ``hs_ref`` once per grid step in the output dtype.
+
     ``masked``: a per-row validity mask (ragged-B packing — cells of
     different batch widths padded to a common B) rides along as an extra
-    input; padded rows freeze their state exactly like the T-edge mask, so
-    they are exact no-ops and h_T/c_T of valid rows are bit-exact.
+    (B, 1) input; padded rows freeze their state exactly like the T-edge
+    mask, so they are exact no-ops and h_T/c_T of valid rows are bit-exact.
 
-    ``quant``: U arrives int8 with a (4,) per-gate scale operand; the int8
-    payload is what sits resident in VMEM (4x smaller), the dot
-    accumulates in fp32 over the scale-free upcast, and the scale is
-    applied to the (B, 4, H) accumulate after the dot — so the only error
-    vs the dequantized oracle is the distributivity of ``(h @ Uq) * s``.
+    ``quant``: U arrives int8 with a per-lane (1, 4H) scale operand (each
+    gate's scale repeated over its H lanes); the int8 payload is what sits
+    resident in VMEM (4x smaller), the dot accumulates in fp32 over the
+    scale-free upcast, and the scale is applied to the (B, 4H) accumulate
+    after the dot — so the only error vs the dequantized oracle is the
+    distributivity of ``(h @ Uq) * s``.
 
-    ``sparse``: U arrives row-compacted (Ha <= H input rows) with an
-    (Ha,) int32 row-index operand; h is gathered to the surviving rows
+    ``sparse``: U arrives row-compacted (Ha <= H input rows) with a
+    (1, Ha) int32 row-index operand; h is gathered to the surviving rows
     before the dot.  Padding rows are zero U rows at index 0 — exact
     no-ops (see kernels.quant.compact_rows).
     """
@@ -145,7 +153,7 @@ def _seq_kernel(*refs, block_t: int, T: int, masked: bool,
     pos += 2
     if masked:
         m_ref, pos = refs[pos], pos + 1
-    hs_ref, hn_ref, cn_ref, h_scr, c_scr = refs[pos:]
+    hs_ref, hn_ref, cn_ref, h_scr, c_scr, ys_scr = refs[pos:]
     t = pl.program_id(1)
 
     @pl.when(t == 0)
@@ -153,51 +161,47 @@ def _seq_kernel(*refs, block_t: int, T: int, masked: bool,
         h_scr[...] = h0_ref[0].astype(jnp.float32)
         c_scr[...] = c0_ref[0].astype(jnp.float32)
 
-    U = u_ref[0]                 # (Hr, 4, H) — resident across the walk
-    Hr, H = U.shape[0], U.shape[2]
-    U2 = U.reshape(Hr, 4 * H)
-    if quant:
-        # scale-free int8 -> f32 upcast ONCE per grid step, outside the
-        # t loop; the per-gate scale rides on the accumulate below
-        U2 = U2.astype(jnp.float32)
-    xw_blk = xw_ref[0]                # (B, block_t, 4, H) — streamed stripe
-    B = xw_blk.shape[0]
+    # (Hr, 4H) — resident across the walk; upcast ONCE per grid step,
+    # outside the t loop (the dot runs f32 x f32 on every weight dtype;
+    # the int8 per-gate scale rides on the accumulate below)
+    U = u_ref[0].astype(jnp.float32)
+    H = hs_ref.shape[-1]
     base = t * block_t
+    row_ok = None if m_ref is None else m_ref[0] != 0        # (B, 1)
 
     def step(i, carry):
-        h, c, ys = carry
-        xw_t = jax.lax.dynamic_index_in_dim(xw_blk, i, axis=1,
-                                            keepdims=False)  # (B, 4, H)
-        h_in = h if not sparse else jnp.take(h, rows_ref[0], axis=1)
+        h, c = carry
+        h_in = h if not sparse else jnp.take(h, rows_ref[0, 0], axis=1)
         acc = jax.lax.dot_general(
-            h_in, U2, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).reshape(B, 4, H)
+            h_in, U, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # (B, 4H)
         if quant:
-            acc = acc * s_ref[0][None, :, None]
-        gates = xw_t.astype(jnp.float32) + acc
-        ig = jax.nn.sigmoid(gates[:, 0])
-        fg = jax.nn.sigmoid(gates[:, 1])
-        gg = jnp.tanh(gates[:, 2])
-        og = jax.nn.sigmoid(gates[:, 3])
-        c_new = fg * c + ig * gg
+            acc = acc * s_ref[0]
+        gates = xw_ref[0, i].astype(jnp.float32) + acc
+        ig = jax.nn.sigmoid(gates[:, 0 * H:1 * H])
+        fg = jax.nn.sigmoid(gates[:, 1 * H:2 * H])
+        gg = jnp.tanh(gates[:, 2 * H:3 * H])
+        og = jax.nn.sigmoid(gates[:, 3 * H:4 * H])
+        # operand order matters under interpret mode: XLA:CPU contracts
+        # this into an FMA, and this order keeps the contraction the same
+        # for every stripe length, so a chunked walk stays bit-equal to
+        # one launch (tests/kernels/test_seq_reversed.py)
+        c_new = ig * gg + fg * c
         h_new = og * jnp.tanh(c_new)
         # T-edge mask: the last block's tail reads BlockSpec padding
         # (undefined, NaN under interpret) — freeze the state there
         valid = base + i < T
-        if m_ref is not None:
-            valid = jnp.logical_and(valid, m_ref[0] != 0)[:, None]  # (B, 1)
+        if row_ok is not None:
+            valid = jnp.logical_and(valid, row_ok)
         h = jnp.where(valid, h_new, h)
         c = jnp.where(valid, c_new, c)
-        ys = jax.lax.dynamic_update_index_in_dim(ys, h, i, axis=1)
-        return h, c, ys
+        ys_scr[i] = h
+        return h, c
 
-    ys0 = jnp.zeros((B, block_t, H), jnp.float32)
-    h, c, ys = jax.lax.fori_loop(
-        0, block_t, step, (h_scr[...], c_scr[...], ys0))
+    h, c = jax.lax.fori_loop(0, block_t, step, (h_scr[...], c_scr[...]))
     h_scr[...] = h
     c_scr[...] = c
-    hs_ref[0] = ys.astype(hs_ref.dtype)
+    hs_ref[0] = ys_scr[...].astype(hs_ref.dtype)
     hn_ref[0] = h.astype(hn_ref.dtype)
     cn_ref[0] = c
 
@@ -206,7 +210,7 @@ def lstm_seq_pallas(U4, xw, h0, c0, *, block_t: int, interpret: bool = True,
                     b_mask=None, u_scales=None, u_rows=None):
     """Sequence-fused LSTM recurrence — ONE kernel launch for all T steps.
 
-    U4 (G,H,4,H); xw (G,B,T,4,H) precomputed input half (+bias);
+    U4 (G,Hr,4,H); xw (G,B,T,4,H) precomputed input half (+bias);
     h0 (G,B,H); c0 (G,B,H).  Returns (hs (G,B,T,H), h_T (G,B,H),
     c_T (G,B,H)).  ``G`` batches independent recurrences (e.g. the cells of
     one wavefront slot); pass G=1 for a single layer.  ``b_mask`` (G,B)
@@ -217,6 +221,11 @@ def lstm_seq_pallas(U4, xw, h0, c0, *, block_t: int, interpret: bool = True,
     accumulate, scale applied post-dot (see kernels.quant).  ``u_rows``
     (G,Ha) int32: U4 is row-compacted to (G,Ha,4,H) — the kernel gathers
     h to the surviving rows (block-sparse row tiles).
+
+    The operands are relaid out here for the TPU's (8, 128) tiling: the
+    gate axis flattens into the lanes (free, contiguous), xw/hs go
+    time-major so a step indexes a leading axis, and the per-cell side
+    operands get a unit second-minor axis ((G,1,·) / (G,B,1) blocks).
     """
     G, B, T, _, H = xw.shape
     Hr = U4.shape[1]
@@ -228,47 +237,49 @@ def lstm_seq_pallas(U4, xw, h0, c0, *, block_t: int, interpret: bool = True,
     sparse = u_rows is not None
     kernel = functools.partial(_seq_kernel, block_t=bt, T=T, masked=masked,
                                quant=quant, sparse=sparse)
+    xw_tm = jnp.swapaxes(xw.reshape(G, B, T, 4 * H), 1, 2)   # (G,T,B,4H)
     in_specs = [
-        pl.BlockSpec((1, B, bt, 4, H), lambda g, t: (g, 0, t, 0, 0)),  # xw
-        pl.BlockSpec((1, Hr, 4, H), lambda g, t: (g, 0, 0, 0)),        # U4
+        pl.BlockSpec((1, bt, B, 4 * H), lambda g, t: (g, t, 0, 0)),  # xw
+        pl.BlockSpec((1, Hr, 4 * H), lambda g, t: (g, 0, 0)),        # U
     ]
-    args = (xw, U4)
+    args = (xw_tm, U4.reshape(G, Hr, 4 * H))
     if quant:
-        in_specs.append(pl.BlockSpec((1, 4), lambda g, t: (g, 0)))     # scales
-        args += (u_scales,)
+        in_specs.append(pl.BlockSpec((1, 1, 4 * H), lambda g, t: (g, 0, 0)))
+        args += (jnp.repeat(u_scales, H, axis=-1)[:, None],)     # scales
     if sparse:
         Ha = u_rows.shape[1]
-        in_specs.append(pl.BlockSpec((1, Ha), lambda g, t: (g, 0)))    # rows
-        args += (u_rows,)
+        in_specs.append(pl.BlockSpec((1, 1, Ha), lambda g, t: (g, 0, 0)))
+        args += (u_rows[:, None],)                               # rows
     in_specs += [
         pl.BlockSpec((1, B, H), lambda g, t: (g, 0, 0)),               # h0
         pl.BlockSpec((1, B, H), lambda g, t: (g, 0, 0)),               # c0
     ]
     args += (h0, c0)
     if masked:
-        in_specs.append(pl.BlockSpec((1, B), lambda g, t: (g, 0)))     # mask
-        args += (b_mask,)
+        in_specs.append(pl.BlockSpec((1, B, 1), lambda g, t: (g, 0, 0)))
+        args += (b_mask[:, :, None],)                            # mask
     hs, h_n, c_n = pl.pallas_call(
         kernel,
         grid=(G, n_t),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, B, bt, H), lambda g, t: (g, 0, t, 0)),        # hs
-            pl.BlockSpec((1, B, H), lambda g, t: (g, 0, 0)),               # h_T
-            pl.BlockSpec((1, B, H), lambda g, t: (g, 0, 0)),               # c_T
+            pl.BlockSpec((1, bt, B, H), lambda g, t: (g, t, 0, 0)),    # hs
+            pl.BlockSpec((1, B, H), lambda g, t: (g, 0, 0)),           # h_T
+            pl.BlockSpec((1, B, H), lambda g, t: (g, 0, 0)),           # c_T
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((G, B, T, H), h0.dtype),
+            jax.ShapeDtypeStruct((G, T, B, H), h0.dtype),
             jax.ShapeDtypeStruct((G, B, H), h0.dtype),
             jax.ShapeDtypeStruct((G, B, H), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((B, H), jnp.float32),   # h — resident across t
-            pltpu.VMEM((B, H), jnp.float32),   # c — resident across t
+            pltpu.VMEM((B, H), jnp.float32),       # h — resident across t
+            pltpu.VMEM((B, H), jnp.float32),       # c — resident across t
+            pltpu.VMEM((bt, B, H), jnp.float32),   # this block's h stripe
         ],
         interpret=interpret,
     )(*args)
-    return hs, h_n, c_n
+    return jnp.swapaxes(hs, 1, 2), h_n, c_n
 
 
 # ===========================================================================
@@ -289,7 +300,8 @@ def _decode_kernel(xw0_ref, w_ref, b_ref, u_ref, h0_ref, c0_ref,
     hoisted input half ``xw0`` (its input exists before launch; the in-
     kernel input GEMM is pl.when-guarded so layer 0 pays no dead MXU
     work); deeper layers compute their input GEMM *in-kernel* from
-    y_scr — one launch per tick instead of L.
+    y_scr — one launch per tick instead of L.  Gates are flattened into
+    the lanes ((B, 4H), weights (H, 4H)) exactly as in ``_seq_kernel``.
 
     The inter-layer value is rounded through ``out_dtype`` and the input
     GEMM through ``xw_dtype`` (the hoist's promotion dtype) before use, so
@@ -297,8 +309,7 @@ def _decode_kernel(xw0_ref, w_ref, b_ref, u_ref, h0_ref, c0_ref,
     bit-identical whenever the hoist promotes to f32 (see lstm_decode).
     """
     l = pl.program_id(0)
-    H = u_ref.shape[-1]
-    B = xw0_ref.shape[0]
+    H = hn_ref.shape[-1]
 
     @pl.when(l == 0)
     def _first():
@@ -312,22 +323,24 @@ def _decode_kernel(xw0_ref, w_ref, b_ref, u_ref, h0_ref, c0_ref,
         # bit-identical for low-precision weight stacks too, not just f32
         # params
         xw = jax.lax.dot_general(
-            y_scr[...], w_ref[0].reshape(H, 4 * H).astype(jnp.float32),
+            y_scr[...], w_ref[0].astype(jnp.float32),
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ).astype(xw_dtype).reshape(B, 4, H)
+        ).astype(xw_dtype)
         xw_scr[...] = (xw + b_ref[0].astype(xw_dtype)).astype(jnp.float32)
 
     gates = xw_scr[...] + jax.lax.dot_general(
-        h0_ref[0].astype(jnp.float32), u_ref[0].reshape(H, 4 * H),
+        h0_ref[0].astype(jnp.float32), u_ref[0].astype(jnp.float32),
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ).reshape(B, 4, H)
-    i = jax.nn.sigmoid(gates[:, 0])
-    f = jax.nn.sigmoid(gates[:, 1])
-    g = jnp.tanh(gates[:, 2])
-    o = jax.nn.sigmoid(gates[:, 3])
-    c = f * c0_ref[0].astype(jnp.float32) + i * g
+    )
+    i = jax.nn.sigmoid(gates[:, 0 * H:1 * H])
+    f = jax.nn.sigmoid(gates[:, 1 * H:2 * H])
+    g = jnp.tanh(gates[:, 2 * H:3 * H])
+    o = jax.nn.sigmoid(gates[:, 3 * H:4 * H])
+    # the operand order of _seq_kernel's c update (see there): a chained
+    # tick stays bit-equal to L per-layer T=1 launches under interpret
+    c = i * g + f * c0_ref[0].astype(jnp.float32)
     h = o * jnp.tanh(c)
     y_scr[...] = h.astype(out_dtype).astype(jnp.float32)
     hn_ref[0] = h.astype(hn_ref.dtype)
@@ -342,7 +355,8 @@ def lstm_decode_pallas(xw0, Ws, bs, Us, h0, c0, *, interpret: bool = True):
     pre-hoisted, so X may differ from H); bs (L,4,H); Us (L,H,4,H);
     h0/c0 (L,B,H) the per-layer recurrent state.  Returns (h_n (L,B,H),
     c_n (L,B,H) fp32): layer l's new hidden state IS its T=1 output, so the
-    top-layer feedback frame is ``h_n[-1]``.
+    top-layer feedback frame is ``h_n[-1]``.  The gate axis is flattened
+    into the lanes here (free, contiguous), as for ``lstm_seq_pallas``.
     """
     L, B, H = h0.shape
     kernel = functools.partial(
@@ -352,25 +366,26 @@ def lstm_decode_pallas(xw0, Ws, bs, Us, h0, c0, *, interpret: bool = True):
         kernel,
         grid=(L,),
         in_specs=[
-            pl.BlockSpec((B, 4, H), lambda l: (0, 0, 0)),      # xw0
-            pl.BlockSpec((1, H, 4, H), lambda l: (l, 0, 0, 0)),  # Ws
-            pl.BlockSpec((1, 4, H), lambda l: (l, 0, 0)),      # bs
-            pl.BlockSpec((1, H, 4, H), lambda l: (l, 0, 0, 0)),  # Us
-            pl.BlockSpec((1, B, H), lambda l: (l, 0, 0)),      # h0
-            pl.BlockSpec((1, B, H), lambda l: (l, 0, 0)),      # c0
+            pl.BlockSpec((B, 4 * H), lambda l: (0, 0)),          # xw0
+            pl.BlockSpec((1, H, 4 * H), lambda l: (l, 0, 0)),    # Ws
+            pl.BlockSpec((1, 1, 4 * H), lambda l: (l, 0, 0)),    # bs
+            pl.BlockSpec((1, H, 4 * H), lambda l: (l, 0, 0)),    # Us
+            pl.BlockSpec((1, B, H), lambda l: (l, 0, 0)),        # h0
+            pl.BlockSpec((1, B, H), lambda l: (l, 0, 0)),        # c0
         ],
         out_specs=[
-            pl.BlockSpec((1, B, H), lambda l: (l, 0, 0)),      # h_n
-            pl.BlockSpec((1, B, H), lambda l: (l, 0, 0)),      # c_n
+            pl.BlockSpec((1, B, H), lambda l: (l, 0, 0)),        # h_n
+            pl.BlockSpec((1, B, H), lambda l: (l, 0, 0)),        # c_n
         ],
         out_shape=[
             jax.ShapeDtypeStruct((L, B, H), h0.dtype),
             jax.ShapeDtypeStruct((L, B, H), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((B, H), jnp.float32),     # y — the layer chain's wire
-            pltpu.VMEM((B, 4, H), jnp.float32),  # xw — this layer's input half
+            pltpu.VMEM((B, H), jnp.float32),       # y — the layer chain's wire
+            pltpu.VMEM((B, 4 * H), jnp.float32),   # xw — this layer's input half
         ],
         interpret=interpret,
-    )(xw0, Ws, bs, Us, h0, c0)
+    )(xw0.reshape(B, 4 * H), Ws.reshape(L, H, 4 * H),
+      bs.reshape(L, 1, 4 * H), Us.reshape(L, H, 4 * H), h0, c0)
     return h_n, c_n

@@ -118,8 +118,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, outdir: str,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # 0.4.x: one dict per module
-        cost = cost[0] if cost else {}
     n_dev = mesh.devices.size
     result = {
         "cell": cell,

@@ -8,25 +8,17 @@ from __future__ import annotations
 import jax
 
 
-def _mesh_kwargs(n_axes: int):
-    # jax.sharding.AxisType (and make_mesh's axis_types kwarg) arrived after
-    # 0.4.x; Auto is the default there anyway, so omit when unavailable.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod; multi_pod adds the 2-pod 'pod' axis (512)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Elastic helper: whatever topology the (restarted) job got."""
-    return jax.make_mesh(tuple(shape), tuple(axes), **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def host_mesh(n: int = 0, model: int = 1):

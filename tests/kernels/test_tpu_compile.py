@@ -1,0 +1,133 @@
+"""Ahead-of-time compile of the recurrent kernels for a described TPU v5e.
+
+Interpret mode never runs the TPU compiler, so these tests compile the main
+path's kernels with ``interpret=False`` for one chip of a described (not
+attached) ``v5e:2x2`` topology, at the widths ``chip_smoke.py`` serves: the
+paper's H340 stacks (BYSDNE, EESEN).  A refused lowering, an illegal block
+shape or a VMEM overflow fails here, at no chip time.  Nothing runs, so
+nothing here says anything about results or speed.
+
+The topology is described inside a fixture, never at import time: only one
+process may hold the TPU compiler library, and every test worker imports
+this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.tiling import select_time_block
+from repro.kernels.gru_cell.ops import gru_decode, gru_seq
+from repro.kernels.lstm_cell.ops import lstm_decode, lstm_seq
+
+H = 340          # BYSDNE / EESEN hidden width
+GATES = {"lstm": 4, "gru": 3}
+#: (weight dtype, activation dtype): all-f32 (EESEN), all-bf16, and the
+#: served BYSDNE mix — bf16 weights under f32 frames and state
+DTYPES = {"fp32": ("float32", "float32"), "bf16": ("bfloat16", "bfloat16"),
+          "bf16w": ("bfloat16", "float32")}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *args, **kwargs):
+    compiled = jax.jit(fn).lower(*args, **kwargs).compile()
+    assert "tpu_custom_call" in compiled.as_text()   # the Mosaic kernel
+    return compiled
+
+
+def _seq_args(sh, family, G, B, T, wdt, adt, *, mask=False, quant=False):
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=sh)
+
+    g = GATES[family]
+    args = [s((G, H, g, H), "int8" if quant else wdt),
+            s((G, B, T, g, H), adt), s((G, B, H), adt)]
+    if family == "lstm":
+        args.append(s((G, B, H), "float32"))
+    kw = {}
+    if mask:
+        kw["b_valid"] = s((G,), "int32")
+    if quant:
+        kw["u_scales"] = s((G, g), "float32")
+    return args, kw
+
+
+def _seq_fn(family, block_t):
+    op = lstm_seq if family == "lstm" else gru_seq
+
+    def fn(*args, **kw):
+        return op(*args, **kw, block_t=block_t, interpret=False)
+
+    return fn
+
+
+@pytest.mark.parametrize("mask", [False, True], ids=["dense", "b_mask"])
+@pytest.mark.parametrize("G", [1, 2])
+@pytest.mark.parametrize("dtypes", list(DTYPES))
+@pytest.mark.parametrize("family", ["lstm", "gru"])
+def test_seq_kernel_compiles(one_chip, family, dtypes, G, mask):
+    """T=30 in stripes of 8: several t-blocks and a ragged T edge."""
+    args, kw = _seq_args(one_chip, family, G, 4, 30, *DTYPES[dtypes],
+                         mask=mask)
+    _compile(_seq_fn(family, 8), *args, **kw)
+
+
+@pytest.mark.parametrize("family", ["lstm", "gru"])
+def test_int8_seq_kernel_compiles(one_chip, family):
+    args, kw = _seq_args(one_chip, family, 2, 4, 30, "float32", "float32",
+                         quant=True)
+    _compile(_seq_fn(family, 8), *args, **kw)
+
+
+@pytest.mark.parametrize("wdt,precision", [("float32", "fp32"),
+                                           ("bfloat16", "bf16")])
+@pytest.mark.parametrize("family", ["lstm", "gru"])
+def test_seq_kernel_compiles_at_vmem_budget_edge(one_chip, family, wdt,
+                                                 precision):
+    """The widest stripe the planner's footprint budget admits at H340:
+    B=7 pads to 8 sublanes and the gate lanes 4H=1360 to 1408, which the
+    footprint model does not count, so this is where scoped VMEM would
+    overflow first."""
+    G, B, T = 2, 7, 256
+    bt = select_time_block(T, B, H, gates=GATES[family], precision=precision)
+    assert bt == 128
+    args, kw = _seq_args(one_chip, family, G, B, T, wdt, "float32")
+    _compile(_seq_fn(family, bt), *args, **kw)
+
+
+@pytest.mark.parametrize("T", [8, 4])
+def test_eesen_slot_compiles(one_chip, T):
+    """EESEN B=8 T=300 plans 8-step stripes, G<=2, and a 4-step remainder."""
+    args, kw = _seq_args(one_chip, "lstm", 2, 8, T, "float32", "float32")
+    _compile(_seq_fn("lstm", T), *args, **kw)
+
+
+@pytest.mark.parametrize("dtypes", list(DTYPES))
+@pytest.mark.parametrize("family", ["lstm", "gru"])
+def test_decode_kernel_compiles(one_chip, family, dtypes):
+    """One chained decode tick through L=5 layers at H340."""
+    wdt, adt = DTYPES[dtypes]
+    L, B, g = 5, 4, GATES[family]
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    args = [s((B, g, H), adt), s((L, H, g, H), wdt), s((L, g, H), wdt),
+            s((L, H, g, H), wdt), s((L, B, H), adt)]
+    if family == "lstm":
+        args.append(s((L, B, H), "float32"))
+    op = lstm_decode if family == "lstm" else gru_decode
+    _compile(lambda *a: op(*a, interpret=False), *args)
