@@ -42,9 +42,10 @@ verify-plans:
 api-demo:
 	$(PY) examples/rnn_api_demo.py
 
-# Traced forward + decode -> artifacts/trace.json (chrome://tracing),
-# metrics_snapshot.json, launch_costs.json (predicted vs measured).
-# CI runs this and uploads the trace as a build artifact.
+# Traced forward + prefill + decode under jax.profiler -> the profile
+# (artifacts/profile/plugins/profile/<run>/*.xplane.pb, the repro.* host
+# phases beside the device operations) and artifacts/metrics_snapshot.json.
+# CI runs this and uploads the profile as a build artifact.
 trace-demo:
 	$(PY) examples/trace_demo.py --out-dir artifacts
 

@@ -71,9 +71,8 @@ MIX = [  # (config, T): different H / L / T — the adaptability scenario
 def _time(fn: Callable, *args, repeat: int = 3) -> float:
     """One measurement discipline for the whole suite: the shared
     runtime timer (1 warm-up call excluded, every repeat fenced with
-    block_until_ready, median reported) — the same code path traced
-    span latencies come from, so bench rows and tracer histograms are
-    directly comparable numbers."""
+    block_until_ready, median reported) — the same code path the
+    calibration replay times launches with."""
     return measure_us(fn, *args, repeats=repeat, warmup=1, reduce="median")
 
 
@@ -487,11 +486,11 @@ def _overhead(fn_off, fn_on, pairs: int = 11, trials: int = 3):
 def _obs_rows(emit, repeats: int = 3) -> None:
     """ISSUE-7: the observability layer, priced.  The same compiled
     forward and chained decode tick with tracing OFF (the default
-    shared no-op tracer) vs ON (spans, fenced launches, metrics,
-    launch-cost table) — bit-identity gated first, because tracing must
-    never alter numerics.  B=8 so kernel compute dominates and the
-    per-slot fence's lost host/device overlap is a small fraction; the
-    smoke test asserts the pairwise overhead estimate stays < 5%."""
+    shared no-op tracer) vs ON (host spans, their profiler
+    annotations, metrics) — bit-identity gated first, because tracing
+    must never alter numerics.  Spans never fence, so the traced path
+    keeps the untraced one's host/device overlap; the smoke test asserts
+    the pairwise overhead estimate stays < 5%."""
     del repeats  # pair count is fixed by the estimator, not --repeats
     cfg, T, B = lstm_config(64, layers=3), 24, 8
     stack = init_lstm_stack(jax.random.PRNGKey(0), cfg, jnp.float32)

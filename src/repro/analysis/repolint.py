@@ -30,10 +30,9 @@ the build before any test runs:
     module's ``measure_samples`` / ``measure_us`` / ``monotonic_s`` /
     ``fence`` so every measurement in the repo shares one fenced clock
     (the PR-4 "one benchmark timer" rule, now machine-checked) — the
-    calibration replay harness included: its measured tables are only
-    comparable to the tracer's launch costs because both come off the
-    same clock.  Launch-side modules (``launch/``, ``checkpoint/``)
-    legitimately stamp wall-clock epoch metadata and are out of scope.
+    calibration replay harness included.  Launch-side modules
+    (``launch/``, ``checkpoint/``) legitimately stamp wall-clock epoch
+    metadata and are out of scope.
 
 ``RL004`` slot-field-read
     ``Slot.signature()``-relevant fields (``wave``, ``chunk_len``,

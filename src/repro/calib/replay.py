@@ -12,9 +12,9 @@ analytic estimate for the same shape, so every entry carries its own
 ``cycles_per_us`` calibration signal.
 
 The input hoist (the X-GEMM) is deliberately NOT replayed: the executor
-issues it outside the ``slot_launch`` span (it overlaps the serial tail),
-so the measured µs here and PR 7's traced launch costs describe the same
-region.
+issues it outside the ``slot_launch`` span (in the slot's ``hoist``
+phase; it overlaps the serial tail), so the measured µs here describe the
+launch alone.
 """
 from __future__ import annotations
 

@@ -43,10 +43,9 @@ from repro.core.perfmodel import (Design, decode_plan_cycles,
                                   slot_launch_cycles)
 from repro.runtime.obs import slot_signature
 
-#: persisted calibration table, next to artifacts/launch_costs.json (the
-#: executed-slot measurement PR 7 records; this one holds *replayed
-#: candidate* shapes, which is what the planner needs to score roads not
-#: taken)
+#: persisted calibration table: the repo's one measured-cost table; it
+#: holds *replayed candidate* shapes, which is what the planner needs to
+#: score roads not taken
 MEASURED_COSTS_PATH = os.path.join("artifacts", "measured_costs.json")
 
 #: schema version — bump whenever entry semantics change; older files are

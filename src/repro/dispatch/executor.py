@@ -134,14 +134,17 @@ def execute(plan: DispatchPlan, params: Dict[int, dict],
     (``runtime.errors.FaultInjector``).
 
     ``tracer`` (optional ``runtime.obs.Tracer``): every packed/chained
-    slot gets a ``hoist`` span (row assembly + input GEMM dispatch) and a
-    *fenced* ``slot_launch`` span (``block_until_ready`` inside the span,
-    so its duration is the launch's wall-clock, not its async dispatch),
-    tagged with the slot signature and uids and fed to the per-signature
-    launch-latency histogram + the predicted-vs-measured launch-cost
-    table; ladder recoveries appear as nested ``fallback_rung`` spans and
-    ``launch_fault`` instants.  None (the default) binds the shared no-op
-    tracer — no events, no fencing, outputs bit-identical.
+    slot gets four sibling spans that together cover its host work —
+    ``hoist`` (each cell's source slice/flip/concat and input GEMM),
+    ``pack`` (row concat + pad, the stacks, the slot's weight operands),
+    ``slot_launch`` (the guarded launch, tagged with the slot signature
+    and uids) and ``scatter`` (the results split back per cell).  Spans
+    are host time and never fence; with ``ExecutionPolicy(trace=True)``
+    each is a ``repro.<name>`` annotation in a ``jax.profiler`` capture,
+    which holds the device time beside it.  Ladder recoveries appear as
+    nested ``fallback_rung`` spans and ``launch_fault`` instants.  None
+    (the default) binds the shared no-op tracer — no events, outputs
+    bit-identical.
     """
     tracer = as_tracer(tracer)
     if on_fault not in ("raise", "fallback"):
@@ -245,43 +248,33 @@ def execute(plan: DispatchPlan, params: Dict[int, dict],
                               interpret=interpret, prepared=prepared,
                               on_fault=on_fault, check_finite=check_finite,
                               inject=inject, report=report,
-                              tracer=tracer, macs=plan.macs)
+                              tracer=tracer)
             continue
         gates = GATES[slot.family]
+        lstm = slot.family == "lstm"
         with tracer.span("hoist", slot=slot.index):
-            xws, hs, cs = [], [], []
-            for grp, b in zip(slot.groups, slot.group_b):
-                xw_rows, h_rows, c_rows = [], [], []
-                for cell in grp:
-                    st = live[cell.uid]
-                    ip: ItemPlan = st["plan"]
-                    layer = _cell_layer_params(params, st, cell)
-                    src = _cell_src(inputs, st, cell, slot.chunk_len)
-                    xw_rows.append(_hoist(layer, src, gates))
-                    h_rows.append(st["h"][(cell.layer, cell.direction)])
-                    if slot.family == "lstm":
-                        c_rows.append(st["c"][(cell.layer, cell.direction)])
-                # cross-B row: parameter-sharing cells concatenate on B
-                # (same U by the share contract — take the lead cell's);
-                # rows narrower than the slot's width pad with zeros,
-                # masked in-kernel to exact no-ops
-                xw_g = _cat_pad(xw_rows, slot.B)
-                xws.append(xw_g)
-                hs.append(_cat_pad(h_rows, slot.B))
-                if slot.family == "lstm":
-                    cs.append(_cat_pad(c_rows, slot.B))
-
-            xw = jnp.stack(xws)          # (G, B, bt, gates, H)
+            # each cell's source chunk and input GEMM, row by row
+            xw_rows = [[_hoist(_cell_layer_params(params, live[c.uid], c),
+                               _cell_src(inputs, live[c.uid], c,
+                                         slot.chunk_len), gates)
+                        for c in grp] for grp in slot.groups]
+        with tracer.span("pack", slot=slot.index):
+            # cross-B row: parameter-sharing cells concatenate on B (same U
+            # by the share contract — take the lead cell's); rows narrower
+            # than the slot's width pad with zeros, masked in-kernel to
+            # exact no-ops
+            xw = jnp.stack([_cat_pad(rows, slot.B)
+                            for rows in xw_rows])   # (G, B, bt, gates, H)
+            h0 = _state_rows(slot, live, "h")       # (G, B, H)
+            c0 = _state_rows(slot, live, "c") if lstm else None
             U, u_scales, u_rows = _slot_weights(slot, params, live,
                                                 quant_cache)
-            h0 = jnp.stack(hs)           # (G, B, H)
-            c0 = jnp.stack(cs) if slot.family == "lstm" else None
-        b_valid = (jnp.asarray(slot.group_b, jnp.int32)
-                   if any(b < slot.B for b in slot.group_b) else None)
-        uids = sorted({c.uid for grp in slot.groups for c in grp})
-        sig = slot.signature() if tracer.enabled else ""
+            b_valid = (jnp.asarray(slot.group_b, jnp.int32)
+                       if any(b < slot.B for b in slot.group_b) else None)
+            uids = sorted({c.uid for grp in slot.groups for c in grp})
+            sig = slot.signature() if tracer.enabled else ""
         with tracer.span("slot_launch", slot=slot.index, sig=sig,
-                         uids=uids) as sp:
+                         uids=uids):
             out, h_n, c_n = _guarded_launch(
                 slot.index, uids,
                 _seq_ladder(slot, U, xw, h0, c0, b_valid,
@@ -289,38 +282,35 @@ def execute(plan: DispatchPlan, params: Dict[int, dict],
                             interpret=interpret),
                 on_fault=on_fault, inject=inject, report=report,
                 tracer=tracer)
-            out, h_n, c_n = tracer.fence((out, h_n, c_n))
-        if tracer.enabled:
-            tracer.observe_launch(sig, _slot_est_cycles(slot, plan.macs),
-                                  sp.dur_us)
-
-        bad: List[int] = []
-        for g, grp in enumerate(slot.groups):
-            off = 0
-            for cell in grp:
-                st = live[cell.uid]
-                nb = st["plan"].item.B
-                key = (cell.layer, cell.direction)
-                st["h"][key] = h_n[g, off:off + nb].astype(h0.dtype)
-                if c_n is not None:
-                    st["c"][key] = c_n[g, off:off + nb]
-                if check_finite and not _rows_finite(
-                        h_n[g, off:off + nb],
-                        None if c_n is None else c_n[g, off:off + nb]):
-                    bad.append(cell.uid)
-                chunk = out[g, off:off + nb].astype(inputs[cell.uid].dtype)
-                if cell.direction == "bwd":
-                    # the kernel walked the chunk in reversed time; store
-                    # the stripe back in original time order
-                    chunk = jnp.flip(chunk, axis=1)
-                st["outs"][key][cell.chunk] = chunk
-                off += nb
-        if bad:
-            bad = sorted(set(bad))
-            raise NonFiniteStateError(
-                f"non-finite recurrent state after slot {slot.index} "
-                f"(uids {bad})", uids=bad, slot=slot.index,
-                where="slot state")
+        with tracer.span("scatter", slot=slot.index):
+            bad: List[int] = []
+            for g, grp in enumerate(slot.groups):
+                off = 0
+                for cell in grp:
+                    st = live[cell.uid]
+                    nb = st["plan"].item.B
+                    key = (cell.layer, cell.direction)
+                    st["h"][key] = h_n[g, off:off + nb].astype(h0.dtype)
+                    if c_n is not None:
+                        st["c"][key] = c_n[g, off:off + nb]
+                    if check_finite and not _rows_finite(
+                            h_n[g, off:off + nb],
+                            None if c_n is None else c_n[g, off:off + nb]):
+                        bad.append(cell.uid)
+                    chunk = out[g, off:off + nb].astype(
+                        inputs[cell.uid].dtype)
+                    if cell.direction == "bwd":
+                        # the kernel walked the chunk in reversed time;
+                        # store the stripe back in original time order
+                        chunk = jnp.flip(chunk, axis=1)
+                    st["outs"][key][cell.chunk] = chunk
+                    off += nb
+            if bad:
+                bad = sorted(set(bad))
+                raise NonFiniteStateError(
+                    f"non-finite recurrent state after slot {slot.index} "
+                    f"(uids {bad})", uids=bad, slot=slot.index,
+                    where="slot state")
 
     for uid, st in live.items():
         it = st["plan"].item
@@ -341,20 +331,13 @@ def execute(plan: DispatchPlan, params: Dict[int, dict],
     return (outputs, states) if collect_state else outputs
 
 
-def _slot_est_cycles(slot, macs: int, X: int = 0) -> float:
-    """The perfmodel's estimate for ONE slot launch — the predicted half
-    of the launch-cost table's predicted-vs-measured pair."""
-    from repro.core.perfmodel import (Design, decode_plan_cycles,
-                                      slot_launch_cycles)
-    from repro.dispatch.planner import DEFAULT_MACS
-
-    design = Design(macs=macs or DEFAULT_MACS, schedule="unfolded")
-    if slot.chained:
-        return decode_plan_cycles(slot.family, slot.H, X or slot.H,
-                                  len(slot.groups), design)
-    return slot_launch_cycles(slot.family, slot.H, slot.chunk_len,
-                              list(slot.group_b), design,
-                              precision=slot.precision)
+def _state_rows(slot, live, part: str):
+    """One sequence slot's (G, B, H) initial ``part`` ("h" or "c") state:
+    each group's cells' rows concatenated on B and padded to the slot's
+    width."""
+    return jnp.stack([_cat_pad([live[c.uid][part][(c.layer, c.direction)]
+                                for c in grp], slot.B)
+                      for grp in slot.groups])
 
 
 def _slot_weights(slot, params, live, cache: dict):
@@ -642,7 +625,7 @@ def _run_chained_slot(slot, params, inputs, live, *, interpret=None,
                       check_finite: bool = False,
                       inject: Optional[FaultInjector] = None,
                       report: Optional[ExecutionReport] = None,
-                      tracer=NULL_TRACER, macs: int = 0):
+                      tracer=NULL_TRACER):
     """Execute a chained decode slot: ONE launch for a whole T=1 tick.
 
     The slot's groups are the L serially dependent layer cells, each the
@@ -663,8 +646,10 @@ def _run_chained_slot(slot, params, inputs, live, *, interpret=None,
     L = len(slot.groups)
 
     with tracer.span("hoist", slot=slot.index):
-        xw0 = _cat_pad([_hoist(stack[0], inputs[c.uid], gates)[:, 0]
-                        for c in row_cells], slot.B)    # (B, gates, H)
+        xw0_rows = [_hoist(stack[0], inputs[c.uid], gates)[:, 0]
+                    for c in row_cells]
+    with tracer.span("pack", slot=slot.index):
+        xw0 = _cat_pad(xw0_rows, slot.B)                 # (B, gates, H)
         prep = ((prepared or {}).get(lead_uid)
                 or prepare_decode_stack(params[lead_uid], slot.family,
                                         precision=slot.precision))
@@ -678,44 +663,40 @@ def _run_chained_slot(slot, params, inputs, live, *, interpret=None,
                                      slot.B) for l in range(L)])
         else:
             c0 = None
-    uids = sorted({c.uid for c in row_cells})
-    sig = slot.signature() if tracer.enabled else ""
-    with tracer.span("slot_launch", slot=slot.index, sig=sig,
-                     uids=uids) as sp:
+        uids = sorted({c.uid for c in row_cells})
+        sig = slot.signature() if tracer.enabled else ""
+    with tracer.span("slot_launch", slot=slot.index, sig=sig, uids=uids):
         h_n, c_n = _guarded_launch(
             slot.index, uids,
             _chained_ladder(slot, xw0, Ws, bs, Us, h0, c0,
                             interpret=interpret),
             on_fault=on_fault, inject=inject, report=report, tracer=tracer)
-        h_n, c_n = tracer.fence((h_n, c_n))
-    if tracer.enabled:
-        X = stack[0]["W"].shape[0]
-        tracer.observe_launch(sig, _slot_est_cycles(slot, macs, X=X),
-                              sp.dur_us)
 
-    off = 0
-    bad: List[int] = []
-    for cell in row_cells:
-        st = live[cell.uid]
-        nb = st["plan"].item.B
-        dtype = inputs[cell.uid].dtype
-        if check_finite and not _rows_finite(
-                h_n[:, off:off + nb],
-                None if c_n is None else c_n[:, off:off + nb]):
-            bad.append(cell.uid)
-        for l in range(L):
-            st["h"][(l, "fwd")] = h_n[l, off:off + nb].astype(h0.dtype)
-            if c_n is not None:
-                st["c"][(l, "fwd")] = c_n[l, off:off + nb]
-            # layer l's new h IS its T=1 output frame
-            st["outs"][(l, "fwd")][0] = \
-                h_n[l, off:off + nb, None].astype(dtype)
-        off += nb
-    if bad:
-        bad = sorted(set(bad))
-        raise NonFiniteStateError(
-            f"non-finite recurrent state after chained slot {slot.index} "
-            f"(uids {bad})", uids=bad, slot=slot.index, where="decode tick")
+    with tracer.span("scatter", slot=slot.index):
+        off = 0
+        bad: List[int] = []
+        for cell in row_cells:
+            st = live[cell.uid]
+            nb = st["plan"].item.B
+            dtype = inputs[cell.uid].dtype
+            if check_finite and not _rows_finite(
+                    h_n[:, off:off + nb],
+                    None if c_n is None else c_n[:, off:off + nb]):
+                bad.append(cell.uid)
+            for l in range(L):
+                st["h"][(l, "fwd")] = h_n[l, off:off + nb].astype(h0.dtype)
+                if c_n is not None:
+                    st["c"][(l, "fwd")] = c_n[l, off:off + nb]
+                # layer l's new h IS its T=1 output frame
+                st["outs"][(l, "fwd")][0] = \
+                    h_n[l, off:off + nb, None].astype(dtype)
+            off += nb
+        if bad:
+            bad = sorted(set(bad))
+            raise NonFiniteStateError(
+                f"non-finite recurrent state after chained slot "
+                f"{slot.index} (uids {bad})", uids=bad, slot=slot.index,
+                where="decode tick")
 
 
 def _chained_ladder(slot, xw0, Ws, bs, Us, h0, c0, *, interpret):

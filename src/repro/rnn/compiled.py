@@ -206,9 +206,11 @@ class CompiledStack:
                 f"{sorted(widths)}")
         self.stats = StackStats()
         #: the observability surface (policy ``trace=True``): a
-        #: runtime.obs.Tracer recording plan/hoist/launch/decode-tick spans
-        #: + metrics; the shared no-op tracer when tracing is off (zero
-        #: events, no fencing — the untraced path is bit-identical)
+        #: runtime.obs.Tracer recording host-time spans (forward, plan,
+        #: hoist, pack, slot_launch, scatter, decode_tick ...) + metrics,
+        #: each span also a ``repro.<name>`` annotation in a jax.profiler
+        #: capture; the shared no-op tracer when tracing is off (zero
+        #: events — the untraced path is bit-identical)
         self.tracer = Tracer() if policy.trace else NULL_TRACER
         #: test/chaos hook: arm with plan slot indices to make launches
         #: raise (see runtime.errors.FaultInjector); disarmed = no-op
@@ -372,21 +374,20 @@ class CompiledStack:
     def forward(self, xs):
         """Whole-sequence evaluation: (B, T, X) -> (B, T, H·dirs) (2-D
         input auto-batches and squeezes back)."""
-        xs, squeeze = self._prep(xs, "forward")
-        B, T, _ = xs.shape
-        if T == 0:
-            raise ValueError("CompiledStack.forward: T=0 sequence")
         tr = self.tracer
-        with tr.span("forward", B=B, T=T) as sp:
+        with tr.span("forward") as sp:
+            xs, squeeze = self._prep(xs, "forward")
+            B, T, _ = xs.shape
+            if T == 0:
+                raise ValueError("CompiledStack.forward: T=0 sequence")
             p = self.lower(B, T, str(xs.dtype))
             rep, guard = self._guard()
             outs = execute(p, {0: self.params}, {0: xs},
                            interpret=self.policy.interpret,
                            quant_cache=self._quant_cache, **guard)
-            outs = tr.fence(outs)
             if tr.enabled:
-                sp.tag(plan=tr.plan_id(p), launches=p.launches)
-        self._account(p, report=rep)
+                sp.tag(B=B, T=T, plan=tr.plan_id(p), launches=p.launches)
+            self._account(p, report=rep)
         ys = outs[0]
         return ys[0] if squeeze else ys
 
@@ -424,12 +425,12 @@ class CompiledStack:
             raise ValueError(
                 f"CompiledStack.prefill: {len(prios)} priorities for "
                 f"{len(seqs)} requests")
-        prepped = [self._prep(x, "prefill") for x in seqs]
-        inputs = {i: x for i, (x, _) in enumerate(prepped)}
-        if any(x.shape[1] == 0 for x in inputs.values()):
-            raise ValueError("CompiledStack.prefill: T=0 sequence")
         tr = self.tracer
         with tr.span("prefill", n_requests=len(seqs)) as sp:
+            prepped = [self._prep(x, "prefill") for x in seqs]
+            inputs = {i: x for i, (x, _) in enumerate(prepped)}
+            if any(x.shape[1] == 0 for x in inputs.values()):
+                raise ValueError("CompiledStack.prefill: T=0 sequence")
             # per-request dtype: a mixed-precision wave must not share
             # launch signatures (the planner keys slots on dtype per item)
             p = self._lower_many(
@@ -441,10 +442,9 @@ class CompiledStack:
                                    interpret=self.policy.interpret,
                                    collect_state=True,
                                    quant_cache=self._quant_cache, **guard)
-            outs, states = tr.fence((outs, states))
             if tr.enabled:
                 sp.tag(plan=tr.plan_id(p), launches=p.launches)
-        self._account(p, report=rep)
+            self._account(p, report=rep)
         res = []
         for i, (_, squeeze) in enumerate(prepped):
             ys = outs[i][0] if squeeze else outs[i]
@@ -461,6 +461,11 @@ class CompiledStack:
         per-layer T=1 plan (L launches).  The policy's schedule preference
         does not apply here — decode is always state-resumed, which only
         the dispatcher paths support.
+
+        Traced (``trace=True``), each tick's ``decode_tick`` span feeds the
+        ``decode_tick_us`` histogram: the HOST time of the tick — its
+        device work runs asynchronously, and is in a ``jax.profiler``
+        capture beside the ``repro.decode_tick`` annotation.
         """
         if self.bidirectional:
             raise ValueError(
@@ -524,12 +529,11 @@ class CompiledStack:
                                    init_state={0: state},
                                    prepared=prepared,
                                    quant_cache=self._quant_cache, **guard)
-            outs, states = tr.fence((outs, states))
             if tr.enabled:
                 sp.tag(plan=tr.plan_id(p), launches=p.launches)
+            self._account(p, decode=True, report=rep)
         if tr.enabled:
             tr.metrics.histogram("decode_tick_us").observe(sp.dur_us)
-        self._account(p, decode=True, report=rep)
         return outs[0], states[0]
 
     # ------------------------------------------------------------------

@@ -116,13 +116,15 @@ class ExecutionPolicy:
     cost_table: path to the measured-cost JSON; None = the default
                ``artifacts/measured_costs.json``.  Only read when
                ``cost_model="measured"``.
-    trace:     record wall-clock spans + metrics for every plan/launch/
-               decode tick on ``CompiledStack.tracer`` (a
-               ``runtime.obs.Tracer`` — Chrome-trace export, latency
-               histograms, predicted-vs-measured launch costs).  Off (the
-               default) binds the shared no-op tracer: no events, no
-               ``block_until_ready`` fencing, outputs bit-identical to
-               the untraced path.
+    trace:     record host-time spans + metrics for every call, plan,
+               slot phase (hoist/pack/slot_launch/scatter) and decode
+               tick on ``CompiledStack.tracer`` (a ``runtime.obs.Tracer``);
+               each span is also a ``repro.<name>`` annotation, so a
+               ``jax.profiler`` capture around the calls puts the host
+               phases on the profiler's clock beside the device time.
+               Spans never fence.  Off (the default) binds the shared
+               no-op tracer: no events, no annotations, outputs
+               bit-identical to the untraced path.
     """
 
     schedule: str = "auto"

@@ -4,6 +4,5 @@ from repro.runtime.errors import (  # noqa: F401
     RequestTimeout, ServingFault)
 from repro.runtime.ft import FTConfig, StragglerWatchdog, TrainLoop  # noqa: F401
 from repro.runtime.obs import (  # noqa: F401
-    LAUNCH_COSTS_PATH, Counter, Histogram, LaunchCostTable, MetricsRegistry,
-    NULL_TRACER, NullTracer, Span, Tracer, as_tracer, fence, measure_us,
-    monotonic_s, slot_signature)
+    Counter, Histogram, MetricsRegistry, NULL_TRACER, NullTracer, Span,
+    Tracer, as_tracer, fence, measure_us, monotonic_s, slot_signature)

@@ -1,66 +1,52 @@
 """Zero-dependency tracing + metrics for the planned execution path.
 
-The ROADMAP's standing caveat is that the analytic ``perfmodel`` optimizes
-a proxy nobody has measured: several launch-count wins are wall-clock
-losses and nothing records per-launch timing to say why.  This module is
-the instrument — it measures the pipeline the perfmodel only estimates,
-and produces the calibration signal the future measured-launch cost model
-will consume.
+The instrument for the executor's host work: which phase of a call
+(planning, the per-cell input GEMMs, row packing, the kernel launch, the
+scatter of results back per cell) the host spends its time in, on the
+same clock as the device's operations.
 
-Three cooperating pieces, stdlib-only (``time`` + ``json``; jax is
-imported lazily, only to fence):
+Two cooperating pieces, stdlib-only (``time``; jax is imported lazily,
+only for the profiler annotation and the benchmark timer):
 
 ``Tracer``
-    Records nested wall-clock spans (``plan``, ``hoist``, ``slot_launch``,
-    ``fallback_rung``, ``decode_tick``, ``admit``, ``request`` ...) tagged
-    with the slot signature (family, G, B, H, block_t, direction,
-    chained), plan id, and request uids.  Launch spans are *fenced*: the
-    instrumented call sites run ``tracer.fence(result)`` —
-    ``jax.block_until_ready`` — inside the span, so a span's duration is
-    the wall-clock of the work it encloses, not of its async dispatch.
-    Exports: ``export_chrome_trace(path)`` (chrome://tracing /
-    ``about:tracing`` trace-event JSON), ``snapshot()`` (machine-readable
-    dict), ``describe()`` (text, merged into ``CompiledStack.describe()``).
+    Records nested host spans (``forward``, ``prefill``, ``decode_tick``,
+    ``plan``, ``verify``, ``hoist``, ``pack``, ``slot_launch``,
+    ``scatter``, ``fallback_rung``, ``admit``, ``request`` ...) tagged
+    with the slot signature, plan id and request uids.  Each live span
+    also enters a ``jax.profiler.TraceAnnotation`` named
+    ``repro.<name>`` (its entry tags ride along as the event's stats), so
+    an operator who captures a ``jax.profiler`` trace around traced calls
+    sees every phase on the profiler's clock, beside the device
+    operations it issued — that is where device time and idle gaps come
+    from.  Spans never fence: a span's duration is the HOST time of the
+    region (the executor issues device work asynchronously), so a traced
+    run runs the same program as an untraced one.  Exports:
+    ``snapshot()`` (machine-readable dict) and ``describe()`` (text,
+    merged into ``CompiledStack.describe()``).
 
 ``MetricsRegistry``
     Counters and streaming histograms (bounded reservoir; nearest-rank
-    p50/p90/p99) for launch latency per slot signature, decode tick
-    latency, queue depth, slot occupancy, degraded launches.
-
-``LaunchCostTable``
-    The predicted-vs-measured record: per slot signature, the perfmodel's
-    ``est_cycles`` next to the measured µs distribution, and their ratio
-    (cycles per measured µs — flat across signatures iff the analytic
-    model ranks shapes correctly; the spread IS the miscalibration).
-    ``save()`` persists a ``signature -> measured µs`` table next to the
-    autotune table (``artifacts/launch_costs.json``) for the
-    measured-launch cost model to consume as its warm-start.
+    p50/p90/p99): decode tick host time, queue depth, slot occupancy,
+    degraded launches, request outcomes.
 
 The whole subsystem is opt-in via ``ExecutionPolicy(trace=True)``.  Off
 (the default), every instrumented call site holds the module-level
 ``NULL_TRACER`` whose ``span()`` returns one reused no-op context manager
-and whose ``fence()`` is the identity — no events, no fencing, no jax
-import, and executor outputs bit-identical to the un-instrumented code
-(asserted in tests/rnn/test_obs.py and priced in BENCH_dispatch's
-``obs_*`` rows).
+— no events, no ``Span`` object, no profiler annotation, and executor
+outputs bit-identical to the un-instrumented code (asserted in
+tests/rnn/test_obs.py and priced in BENCH_dispatch's ``obs_*`` rows).
 
 ``measure_us`` is the one benchmark timer (warmup exclusion +
 ``block_until_ready`` fencing + median/min reduction) — the bench suites
-route through it so bench medians and traced span durations share a
-single measurement code path.
+and the calibration replay route through it so every fenced timing in
+the repo shares a single measurement code path.
 """
 from __future__ import annotations
 
-import json
 import math
-import os
 import statistics
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
-
-#: persisted measured-launch table, next to artifacts/autotune_table.json
-LAUNCH_COSTS_PATH = os.path.join("artifacts", "launch_costs.json")
-
 
 # ---------------------------------------------------------------------------
 # metrics
@@ -179,89 +165,24 @@ class MetricsRegistry:
 
 
 # ---------------------------------------------------------------------------
-# predicted vs measured
-# ---------------------------------------------------------------------------
-
-
-class LaunchCostTable:
-    """Per-slot-signature measured launch cost next to the perfmodel's
-    estimate.  ``cycles_per_us = est_cycles / median measured µs`` is the
-    calibration signal: if the analytic model were right up to one clock
-    constant, the ratio would be flat across signatures — the spread is
-    exactly what the measured-launch cost model (ROADMAP) must correct."""
-
-    def __init__(self):
-        self._est: Dict[str, float] = {}
-        self._us: Dict[str, Histogram] = {}
-
-    def record(self, sig: str, est_cycles: float, us: float) -> None:
-        self._est[sig] = float(est_cycles)
-        h = self._us.get(sig)
-        if h is None:
-            h = self._us[sig] = Histogram()
-        h.observe(us)
-
-    def __len__(self) -> int:
-        return len(self._us)
-
-    def snapshot(self) -> Dict[str, Dict[str, float]]:
-        out = {}
-        for sig in sorted(self._us):
-            h = self._us[sig]
-            med = h.quantile(0.5)
-            out[sig] = {"n": h.count, "med_us": med,
-                        "p90_us": h.quantile(0.9),
-                        "est_cycles": self._est[sig],
-                        "cycles_per_us": (self._est[sig] / med
-                                          if med > 0 else 0.0)}
-        return out
-
-    def describe(self) -> str:
-        rows = self.snapshot()
-        if not rows:
-            return "launch costs: (none measured)"
-        lines = ["launch costs (predicted vs measured):"]
-        for sig, r in rows.items():
-            lines.append(
-                f"  {sig}: n={r['n']} med={r['med_us']:.1f}us "
-                f"est={r['est_cycles']:.0f}cy "
-                f"ratio={r['cycles_per_us']:.2f}cy/us")
-        return "\n".join(lines)
-
-    def save(self, path: str = LAUNCH_COSTS_PATH) -> str:
-        """Persist ``signature -> measured µs summary`` (merging with an
-        existing table: this run's signatures overwrite, unseen ones are
-        kept — the same accumulate-across-runs contract as the autotune
-        table next door)."""
-        merged: Dict[str, Dict[str, float]] = {}
-        if os.path.exists(path):
-            with open(path) as f:
-                merged = json.load(f).get("signatures", {})
-        merged.update(self.snapshot())
-        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-        with open(path, "w") as f:
-            json.dump({"signatures": merged}, f, indent=1, sort_keys=True)
-        return path
-
-    @staticmethod
-    def load(path: str = LAUNCH_COSTS_PATH) -> Dict[str, Dict[str, float]]:
-        with open(path) as f:
-            return json.load(f)["signatures"]
-
-
-# ---------------------------------------------------------------------------
 # spans + tracer
 # ---------------------------------------------------------------------------
 
 
+#: the prefix of every span's profiler annotation (``repro.slot_launch``)
+PROFILER_PREFIX = "repro."
+
+
 class Span:
     """One completed (or in-flight) traced region.  Context manager:
-    entering stamps ``start_us``, exiting stamps ``dur_us`` and files the
-    span with its tracer.  ``depth`` is the nesting level at entry (the
+    entering stamps ``start_us`` and opens the span's profiler annotation
+    (``repro.<name>``, the entry tags as its stats); exiting closes it,
+    stamps ``dur_us`` — host time, nothing is fenced — and files the span
+    with its tracer.  ``depth`` is the nesting level at entry (the
     span-tree proof the tests assert)."""
 
     __slots__ = ("name", "track", "tags", "start_us", "dur_us", "depth",
-                 "_tracer")
+                 "_tracer", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, track: str, tags: dict):
         self._tracer = tracer
@@ -271,19 +192,26 @@ class Span:
         self.start_us: float = 0.0
         self.dur_us: Optional[float] = None
         self.depth: int = 0
+        self._annotation = None
 
     def tag(self, **tags) -> "Span":
         self.tags.update(tags)
         return self
 
     def __enter__(self) -> "Span":
-        self.depth = len(self._tracer._stack)
-        self._tracer._stack.append(self)
-        self.start_us = self._tracer.now_us()
+        tr = self._tracer
+        self.depth = len(tr._stack)
+        tr._stack.append(self)
+        self._annotation = tr._annotate(PROFILER_PREFIX + self.name,
+                                        **self.tags)
+        self._annotation.__enter__()
+        self.start_us = tr.now_us()
         return self
 
     def __exit__(self, *exc) -> None:
         self.dur_us = self._tracer.now_us() - self.start_us
+        self._annotation.__exit__(*exc)
+        self._annotation = None
         self._tracer._stack.pop()
         self._tracer.events.append(self)
 
@@ -313,34 +241,31 @@ _NULL_SPAN = _NullSpan()
 
 
 class Tracer:
-    """Nested wall-clock span recorder + metrics + launch-cost table.
+    """Nested host-time span recorder + metrics.
 
     Timestamps are µs since tracer construction (``time.perf_counter``
     based).  Spans nest per the call stack (single-threaded, like the
-    executor); retroactive spans (``span_at``) and instants land on named
-    *tracks* — chrome://tracing rows — so per-request admit→retire spans
-    live on a "requests" track beside the "exec" track's launches."""
+    executor) and each one is also a ``repro.<name>`` annotation in any
+    ``jax.profiler`` trace being captured; retroactive spans (``span_at``)
+    and instants are recorded in memory only, on named *tracks* — so
+    per-request admit→retire spans live on a "requests" track beside the
+    "exec" track's launches."""
 
     enabled = True
 
     def __init__(self):
+        from jax.profiler import TraceAnnotation
+
+        self._annotate = TraceAnnotation
         self._t0 = time.perf_counter()
         self.events: List[Span] = []
         self._stack: List[Span] = []
         self.metrics = MetricsRegistry()
-        self.launch_costs = LaunchCostTable()
         self._plan_ids: Dict[int, int] = {}
 
     # -- time ----------------------------------------------------------
     def now_us(self) -> float:
         return (time.perf_counter() - self._t0) * 1e6
-
-    def fence(self, value):
-        """``jax.block_until_ready`` — call INSIDE a span so its duration
-        measures the enclosed work, not its async dispatch."""
-        import jax
-
-        return jax.block_until_ready(value)
 
     # -- recording -----------------------------------------------------
     def span(self, name: str, track: str = "exec", **tags) -> Span:
@@ -372,70 +297,22 @@ class Tracer:
             self._plan_ids[id(plan)] = pid
         return pid
 
-    def observe_launch(self, sig: str, est_cycles: float,
-                       dur_us: float) -> None:
-        """One measured launch: feeds both the per-signature latency
-        histogram and the predicted-vs-measured table."""
-        self.metrics.histogram(f"launch_us/{sig}").observe(dur_us)
-        self.launch_costs.record(sig, est_cycles, dur_us)
-
     # -- export --------------------------------------------------------
-    def export_chrome_trace(self, path: str) -> str:
-        """Write chrome://tracing (about:tracing / Perfetto) trace-event
-        JSON: complete ("X") events for spans, instant ("i") events for
-        markers, metadata thread names for tracks."""
-        tracks = {"exec": 0}
-        events: List[dict] = [
-            {"ph": "M", "name": "process_name", "pid": 0, "tid": 0,
-             "args": {"name": "repro"}},
-        ]
-        for sp in self.events:
-            tid = tracks.setdefault(sp.track, len(tracks))
-            ev = {"name": sp.name, "pid": 0, "tid": tid,
-                  "ts": round(sp.start_us, 3), "args": sp.tags}
-            if sp.dur_us is None:
-                ev.update(ph="i", s="t")
-            else:
-                ev.update(ph="X", dur=round(sp.dur_us, 3))
-            events.append(ev)
-        for track, tid in tracks.items():
-            events.append({"ph": "M", "name": "thread_name", "pid": 0,
-                           "tid": tid, "args": {"name": track}})
-        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-        with open(path, "w") as f:
-            json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, f)
-        return path
-
     def snapshot(self) -> Dict[str, Any]:
-        """Machine-readable state: span count, metrics (counters +
-        histogram quantiles), the per-signature launch-cost table, and the
-        aggregate predicted-vs-measured ratio."""
-        costs = self.launch_costs.snapshot()
-        ratios = [r["cycles_per_us"] for r in costs.values()
-                  if r["cycles_per_us"] > 0]
-        return {
-            "spans": len(self.events),
-            "metrics": self.metrics.snapshot(),
-            "launch_costs": costs,
-            "predicted_vs_measured": {
-                "signatures": len(ratios),
-                "mean_cycles_per_us": (sum(ratios) / len(ratios)
-                                       if ratios else 0.0),
-                "spread": (max(ratios) / min(ratios)
-                           if len(ratios) > 1 and min(ratios) > 0 else 1.0),
-            },
-        }
+        """Machine-readable state: span count and metrics (counters +
+        histogram quantiles)."""
+        return {"spans": len(self.events),
+                "metrics": self.metrics.snapshot()}
 
     def describe(self) -> str:
         lines = [f"trace: {len(self.events)} spans"]
         lines += self.metrics.describe().splitlines()
-        lines += self.launch_costs.describe().splitlines()
         return "\n".join(lines)
 
 
 class NullTracer:
     """The disabled tracer: every hook is a no-op, ``enabled`` is False so
-    instrumented sites skip fencing/metric work entirely.  One shared
+    instrumented sites skip tag and metric work entirely.  One shared
     instance (``NULL_TRACER``) serves every untraced stack."""
 
     enabled = False
@@ -443,13 +320,9 @@ class NullTracer:
     def __init__(self):
         self.events: List[Span] = ()  # immutable: nothing ever records
         self.metrics = MetricsRegistry()
-        self.launch_costs = LaunchCostTable()
 
     def now_us(self) -> float:
         return 0.0
-
-    def fence(self, value):
-        return value
 
     def span(self, name: str, track: str = "exec", **tags) -> _NullSpan:
         return _NULL_SPAN
@@ -464,14 +337,8 @@ class NullTracer:
     def plan_id(self, plan) -> int:
         return 0
 
-    def observe_launch(self, sig, est_cycles, dur_us) -> None:
-        pass
-
     def snapshot(self) -> Dict[str, Any]:
-        return {"spans": 0, "metrics": self.metrics.snapshot(),
-                "launch_costs": {}, "predicted_vs_measured": {
-                    "signatures": 0, "mean_cycles_per_us": 0.0,
-                    "spread": 1.0}}
+        return {"spans": 0, "metrics": self.metrics.snapshot()}
 
     def describe(self) -> str:
         return "trace: disabled"
@@ -501,8 +368,8 @@ def monotonic_s() -> float:
 
 def fence(value):
     """``jax.block_until_ready`` as a function, for callers that time
-    around device work without holding a ``Tracer`` (the tracer's
-    ``fence`` method is the traced-path equivalent)."""
+    around device work (the fault-tolerant train loop); traced spans never
+    fence."""
     import jax
 
     return jax.block_until_ready(value)
@@ -533,9 +400,7 @@ def measure_us(fn: Callable, *args, repeats: int = 3, warmup: int = 1,
     """Time ``fn(*args)``: ``warmup`` untimed calls (compile/plan-cache
     exclusion), then ``repeats`` calls each fenced with
     ``jax.block_until_ready``, reduced by ``median`` (default) or ``min``.
-    Returns µs.  This is the measurement discipline of the executor's
-    ``slot_launch`` spans, shared so bench rows and traced latencies are
-    comparable numbers."""
+    Returns µs."""
     if reduce not in ("median", "min"):
         raise ValueError(f"measure_us: reduce={reduce!r} invalid; "
                          "allowed: median, min")
@@ -548,7 +413,7 @@ def slot_signature(family: str, H: int, G: int, B: int, chunk_len: int,
                    dtype: str, directions: Sequence[str] = ("fwd",),
                    chained: bool = False, precision: str = "fp32") -> str:
     """The canonical slot-signature string every layer tags launches with
-    (and the launch-cost table keys on): family, G-batch width, padded B,
+    (and the measured-cost table keys on): family, G-batch width, padded B,
     H, T-stripe, dtype, direction mix, precision, chained flag.  The
     precision token (``|pint8`` / ``|pbf16``) is emitted only for
     non-fp32, so pre-existing persisted signatures stay valid — and an
@@ -561,6 +426,6 @@ def slot_signature(family: str, H: int, G: int, B: int, chunk_len: int,
 
 
 __all__ = ["Tracer", "NullTracer", "NULL_TRACER", "as_tracer", "Span",
-           "Counter", "Histogram", "MetricsRegistry", "LaunchCostTable",
-           "LAUNCH_COSTS_PATH", "measure_us", "measure_samples",
-           "monotonic_s", "fence", "slot_signature"]
+           "PROFILER_PREFIX", "Counter", "Histogram", "MetricsRegistry",
+           "measure_us", "measure_samples", "monotonic_s", "fence",
+           "slot_signature"]

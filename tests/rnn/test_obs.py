@@ -1,9 +1,11 @@
-"""ISSUE-7 integration: tracing threaded through compile -> forward ->
-prefill -> decode and the serving engine.  The claims: a traced run emits
-the expected nested span tree + per-signature launch metrics + the
-predicted-vs-measured table; tracing OFF leaves outputs bit-identical
-(and binds the shared no-op tracer); the fault trail is a ring buffer."""
-import json
+"""Tracing threaded through compile -> forward -> prefill -> decode and
+the serving engine.  The claims: a traced run emits the expected nested
+span tree, four sibling phases per slot (hoist, pack, slot_launch,
+scatter), each a ``repro.*`` annotation in a jax.profiler capture, and
+never fences; tracing OFF leaves outputs bit-identical (and binds the
+shared no-op tracer); the fault trail is a ring buffer."""
+import collections
+import glob
 
 import jax
 import jax.numpy as jnp
@@ -40,7 +42,7 @@ def _traced_session(cs):
     return y_t
 
 
-def test_traced_run_emits_expected_span_tree(tmp_path):
+def test_traced_run_emits_expected_span_tree():
     cs = rnn.compile(_stack(), rnn.ExecutionPolicy(interpret=True,
                                                    trace=True))
     _traced_session(cs)
@@ -48,13 +50,13 @@ def test_traced_run_emits_expected_span_tree(tmp_path):
     assert tr.enabled and tr is not NULL_TRACER
 
     names = {s.name for s in tr.events}
-    assert {"forward", "prefill", "decode_tick", "plan", "hoist",
-            "slot_launch", "plan_candidates"} <= names
+    assert {"forward", "prefill", "decode_tick", "plan", "hoist", "pack",
+            "slot_launch", "scatter", "plan_candidates"} <= names
     # nesting: the API-level spans are roots, the per-slot work nests
     for s in tr.events:
         if s.name in ("forward", "prefill", "decode_tick"):
             assert s.depth == 0
-        if s.name in ("plan", "hoist", "slot_launch"):
+        if s.name in PHASES + ("plan",):
             assert s.depth >= 1
     # every launch span carries its slot signature and a real duration
     launches = [s for s in tr.events if s.name == "slot_launch"]
@@ -65,27 +67,106 @@ def test_traced_run_emits_expected_span_tree(tmp_path):
     # the 3 chained decode launches share one signature
     assert sum("|chained" in s.tags["sig"] for s in launches) == 3
 
-    # metrics: decode tick histogram saw the 3 ticks; launch quantiles +
-    # predicted-vs-measured ratio are populated per signature
+    # metrics: the decode tick histogram saw the 3 ticks' host time; the
+    # fenced per-signature launch table is gone
     snap = tr.snapshot()
     assert snap["metrics"]["histograms"]["decode_tick_us"]["count"] == 3
-    assert snap["launch_costs"]
-    for sig, row in snap["launch_costs"].items():
-        assert row["med_us"] > 0 and row["est_cycles"] > 0
-        assert row["cycles_per_us"] > 0
-    pvm = snap["predicted_vs_measured"]
-    assert pvm["signatures"] == len(snap["launch_costs"])
-    assert pvm["mean_cycles_per_us"] > 0
-
-    # chrome export round-trips as valid trace-event JSON
-    path = tr.export_chrome_trace(str(tmp_path / "trace.json"))
-    data = json.loads(open(path).read())
-    X = [e for e in data["traceEvents"] if e["ph"] == "X"]
-    assert {"forward", "decode_tick", "slot_launch"} <= {e["name"]
-                                                         for e in X}
+    assert set(snap) == {"spans", "metrics"}
+    assert not any(k.startswith("launch_us/")
+                   for k in snap["metrics"]["histograms"])
     # describe() surfaces the observability section through the facade
     assert "observability:" in cs.describe()
-    assert "launch costs" in cs.describe()
+
+
+PHASES = ("hoist", "pack", "slot_launch", "scatter")
+
+
+def _phases_by_slot(events, root: str):
+    """{slot index: [phase names in time order]} under the last ``root``
+    span, with each phase's depth."""
+    (top,) = [s for s in events if s.name == root][-1:]
+    t0, t1 = top.start_us, top.start_us + top.dur_us
+    slots = collections.defaultdict(list)
+    for s in sorted(events, key=lambda s: s.start_us):
+        if s.name in PHASES and t0 <= s.start_us <= t1:
+            slots[s.tags["slot"]].append((s.name, s.depth))
+    return slots
+
+
+def test_each_packed_slot_has_four_sibling_phases():
+    cs = rnn.compile(_stack(), rnn.ExecutionPolicy(interpret=True,
+                                                   trace=True))
+    cs.forward(_xs())
+    slots = _phases_by_slot(cs.tracer.events, "forward")
+    assert sorted(slots) == [sl.index for sl in cs.plan.slots]
+    for phases in slots.values():
+        # siblings, directly under forward, in execution order
+        assert phases == [(n, 1) for n in PHASES]
+
+
+def test_chained_decode_slot_has_the_same_four_phases():
+    cs = rnn.compile(_stack(), rnn.ExecutionPolicy(interpret=True,
+                                                   trace=True))
+    ys, state = cs.prefill(_xs())
+    cs.decode(ys[:, -1:], state)
+    assert cs.last_decode_plan.slots[0].chained
+    slots = _phases_by_slot(cs.tracer.events, "decode_tick")
+    assert slots == {0: [(n, 1) for n in PHASES]}
+
+
+def _host_events(profile_dir):
+    (path,) = glob.glob(str(profile_dir / "plugins/profile/*/*.xplane.pb"))
+    names = collections.Counter()
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("repro."):
+                    names[e.name] += 1
+    return names
+
+
+@pytest.mark.parametrize("trace", [True, False])
+def test_profiler_capture_holds_the_repro_phases(tmp_path, trace):
+    """What an operator sees: ``trace=True`` plus a jax.profiler capture
+    puts each host phase in the .xplane.pb as a ``repro.*`` event."""
+    cs = rnn.compile(_stack(), rnn.ExecutionPolicy(interpret=True,
+                                                   trace=trace))
+    xs = _xs()
+    cs.forward(xs)  # plan and compile outside the capture
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        jax.block_until_ready(cs.forward(xs))
+    finally:
+        jax.profiler.stop_trace()
+    names = _host_events(tmp_path)
+    if not trace:
+        assert names == {}
+        return
+    n_slots = len(cs.plan.slots)
+    assert names["repro.forward"] == 1
+    for phase in PHASES:
+        assert names[f"repro.{phase}"] == n_slots
+
+
+def test_traced_forward_never_fences(monkeypatch):
+    cs = rnn.compile(_stack(), rnn.ExecutionPolicy(interpret=True,
+                                                   trace=True))
+    xs = _xs()
+    cs.forward(xs)
+    calls = []
+    real = jax.block_until_ready
+
+    def counting(x):
+        calls.append(x)
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", counting)
+    ys, state = cs.prefill(xs)
+    cs.forward(xs)
+    cs.decode(ys[:, -1:], state)
+    assert calls == []
+    assert {"forward", "prefill", "decode_tick", "slot_launch"} <= {
+        s.name for s in cs.tracer.events}
 
 
 def test_trace_off_is_bit_identical_and_binds_null_tracer():

@@ -1,14 +1,11 @@
 """runtime.obs unit tests: span nesting, histogram quantiles on known
-data, the no-op tracer path, chrome-trace JSON schema round-trip, the
-predicted-vs-measured launch-cost table, and the shared bench timer."""
-import json
-
+data, the no-op tracer path, each span's profiler annotation, and the
+shared bench timer."""
 import pytest
 
-from repro.runtime.obs import (LAUNCH_COSTS_PATH, Counter, Histogram,
-                               LaunchCostTable, MetricsRegistry, NULL_TRACER,
-                               NullTracer, Tracer, as_tracer, measure_us,
-                               slot_signature)
+from repro.runtime.obs import (PROFILER_PREFIX, Counter, Histogram,
+                               MetricsRegistry, NULL_TRACER, NullTracer,
+                               Tracer, as_tracer, measure_us, slot_signature)
 
 
 # ---------------------------------------------------------------------------
@@ -110,21 +107,6 @@ def test_plan_id_stable_and_sequential():
     assert tr.plan_id(a) == 0
 
 
-def test_observe_launch_feeds_histogram_and_table():
-    tr = Tracer()
-    sig = slot_signature("lstm", 64, 2, 1, 12, "float32")
-    for us in (100.0, 110.0, 120.0):
-        tr.observe_launch(sig, est_cycles=550.0, dur_us=us)
-    snap = tr.snapshot()
-    assert snap["metrics"]["histograms"][f"launch_us/{sig}"]["count"] == 3
-    row = snap["launch_costs"][sig]
-    assert row["n"] == 3 and row["med_us"] == 110.0
-    assert row["cycles_per_us"] == pytest.approx(5.0)
-    assert snap["predicted_vs_measured"]["signatures"] == 1
-    assert snap["predicted_vs_measured"]["mean_cycles_per_us"] == \
-        pytest.approx(5.0)
-
-
 def test_slot_signature_format():
     assert (slot_signature("lstm", 64, 2, 1, 12, "float32")
             == "lstm|H64|G2|B1|bt12|float32|fwd")
@@ -146,13 +128,11 @@ def test_null_tracer_is_inert():
     with sp as s:
         s.tag(b=2)
     assert NULL_TRACER.events == ()     # nothing ever recorded
-    obj = {"h": [1, 2]}
-    assert NULL_TRACER.fence(obj) is obj  # identity, no jax import needed
     NULL_TRACER.instant("x")
     NULL_TRACER.span_at("x", 0.0, 1.0)
-    NULL_TRACER.observe_launch("sig", 1.0, 1.0)
-    assert NULL_TRACER.snapshot()["spans"] == 0
-    assert len(NULL_TRACER.launch_costs) == 0
+    snap = NULL_TRACER.snapshot()
+    assert set(snap) == {"spans", "metrics"} and snap["spans"] == 0
+    assert not hasattr(NULL_TRACER, "fence")
 
 
 def test_as_tracer():
@@ -162,79 +142,73 @@ def test_as_tracer():
 
 
 # ---------------------------------------------------------------------------
-# chrome trace export
+# profiler annotations
 # ---------------------------------------------------------------------------
 
 
-def test_chrome_trace_schema_round_trip(tmp_path):
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each enter and
+    exit with the annotation's name and tags."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **tags):
+        log = self.log
+
+        class _Ann:
+            def __enter__(self):
+                log.append(("enter", name, tags))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name, exc[0]))
+
+        return _Ann()
+
+
+def test_span_enters_and_exits_its_profiler_annotation():
     tr = Tracer()
-    with tr.span("outer"):
-        with tr.span("inner", slot=0):
+    tr._annotate = ann = _Annotations()
+    with tr.span("forward", B=2):
+        with tr.span("slot_launch", slot=0, sig="lstm|H8"):
             pass
-        tr.instant("marker", slot=1)
-    tr.span_at("request", tr.events[0].start_us,
-               tr.events[0].start_us + 5.0, track="requests", uid=3)
-    path = tr.export_chrome_trace(str(tmp_path / "trace.json"))
-
-    data = json.loads(open(path).read())
-    assert data["displayTimeUnit"] == "ms"
-    evs = data["traceEvents"]
-    by_ph = {}
-    for e in evs:
-        by_ph.setdefault(e["ph"], []).append(e)
-    # process metadata + one thread_name per track (exec, requests)
-    meta_names = {e["name"]: e for e in by_ph["M"] if e["name"] != ""}
-    assert meta_names["process_name"]["args"]["name"] == "repro"
-    thread_names = {e["args"]["name"] for e in by_ph["M"]
-                    if e["name"] == "thread_name"}
-    assert thread_names == {"exec", "requests"}
-    # complete events: inner's interval nests inside outer's
-    X = {e["name"]: e for e in by_ph["X"]}
-    assert set(X) == {"outer", "inner", "request"}
-    assert X["outer"]["ts"] <= X["inner"]["ts"]
-    assert (X["inner"]["ts"] + X["inner"]["dur"]
-            <= X["outer"]["ts"] + X["outer"]["dur"] + 1e-3)
-    # the instant marker
-    (inst,) = by_ph["i"]
-    assert inst["name"] == "marker" and inst["s"] == "t"
-    assert inst["args"] == {"slot": 1}
-    # exec and requests land on different tids
-    assert X["request"]["tid"] != X["inner"]["tid"]
+    tr.span_at("request", 0.0, 1.0, uid=3)   # retroactive: memory only
+    tr.instant("launch_fault", slot=0)       # instants: memory only
+    assert PROFILER_PREFIX == "repro."
+    assert ann.log == [
+        ("enter", "repro.forward", {"B": 2}),
+        ("enter", "repro.slot_launch", {"slot": 0, "sig": "lstm|H8"}),
+        ("exit", "repro.slot_launch", None),
+        ("exit", "repro.forward", None),
+    ]
 
 
-# ---------------------------------------------------------------------------
-# launch-cost persistence
-# ---------------------------------------------------------------------------
+def test_span_closes_its_annotation_when_the_region_raises():
+    tr = Tracer()
+    tr._annotate = ann = _Annotations()
+    with pytest.raises(KeyError):
+        with tr.span("outer"):
+            with tr.span("inner"):
+                raise KeyError("boom")
+    assert [(k, n) for k, n, _ in ann.log] == [
+        ("enter", "repro.outer"), ("enter", "repro.inner"),
+        ("exit", "repro.inner"), ("exit", "repro.outer")]
+    assert ann.log[2][2] is KeyError
+    assert tr._stack == []                   # the span stack unwound
+    assert [s.name for s in tr.events] == ["inner", "outer"]
 
 
-def test_launch_cost_table_save_load_merge(tmp_path):
-    path = str(tmp_path / "launch_costs.json")
-    t1 = LaunchCostTable()
-    t1.record("sigA", 100.0, 10.0)
-    t1.record("sigB", 200.0, 20.0)
-    assert t1.save(path) == path
-    loaded = LaunchCostTable.load(path)
-    assert set(loaded) == {"sigA", "sigB"}
-    assert loaded["sigA"]["cycles_per_us"] == pytest.approx(10.0)
+def test_null_tracer_opens_no_annotation(monkeypatch):
+    import jax.profiler
 
-    # merge contract: this run's signatures overwrite, unseen ones kept
-    t2 = LaunchCostTable()
-    t2.record("sigB", 200.0, 40.0)
-    t2.record("sigC", 300.0, 30.0)
-    t2.save(path)
-    merged = LaunchCostTable.load(path)
-    assert set(merged) == {"sigA", "sigB", "sigC"}
-    assert merged["sigA"]["med_us"] == 10.0   # kept from run 1
-    assert merged["sigB"]["med_us"] == 40.0   # overwritten by run 2
-    assert "sigA" in open(path).read()        # plain JSON on disk
-    assert LAUNCH_COSTS_PATH.endswith("launch_costs.json")
+    def refuse(*a, **k):
+        raise AssertionError("the untraced path opened an annotation")
 
-
-def test_launch_cost_describe():
-    t = LaunchCostTable()
-    assert "none measured" in t.describe()
-    t.record("sig", 100.0, 10.0)
-    assert "10.0us" in t.describe() and "100cy" in t.describe()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    with NULL_TRACER.span("forward", B=1):
+        with NULL_TRACER.span("slot_launch", slot=0):
+            pass
+    assert NULL_TRACER.events == ()
 
 
 # ---------------------------------------------------------------------------
