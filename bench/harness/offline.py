@@ -6,7 +6,9 @@ The window then calls ``CompiledStack.forward`` back to back on inputs
 drawn from the seed, call by call, until ``seconds`` have passed; the call
 under way at that moment completes and counts.  Frames per second is the
 frames of all completed calls over the time from the window's start to
-the last call's end.
+the last call's end.  Each call runs in a ``bench.forward`` span, the
+unit a truncated trace is counted in (``harness/trace.py``); every call
+does the same work.
 
 Correctness: the outputs of a sample of calls (drawn from the seed, call 0
 always among them) are kept on the device; after the window and the
@@ -72,7 +74,7 @@ def run(cell, seed: int, seconds: float, profile_dir, devices, compiles,
     setup_s = now() - clock0
 
     kept, calls = {}, 0
-    with profiled(profile_dir) as tr:
+    with profiled(profile_dir, call="bench.forward") as tr:
         with annotate("bench.window"):
             t0 = now()
             while True:
@@ -122,7 +124,8 @@ def run(cell, seed: int, seconds: float, profile_dir, devices, compiles,
         peaks={}, window_s=window,
         counters=counters, host={},
         work={"lstm_seq": seq_work(shape, frames, calls)},
-        model_flops=frames * model_flops_per_frame(shape), trace=tr.summary)
+        model_flops=frames * model_flops_per_frame(shape), trace=tr.summary,
+        call_work={"lstm_seq": seq_work(shape, B * T)})
     return Outcome(attempted=calls * B, failed=failed,
                    setup_s=setup_s,
                    metrics={"offline_frames_per_s": stats.rate(frames,

@@ -24,24 +24,52 @@ class RunRecord:
     work: Dict[str, Tuple[float, float]]  # kernel -> (flops, bytes)
     model_flops: float                    # model FLOPs of the window's frames
     trace: Optional[TraceSummary] = None
+    #: kernel -> (flops, bytes) of one call, where every call of the
+    #: window does the same work; what a truncated trace's covered calls
+    #: are counted by
+    call_work: Dict[str, Tuple[float, float]] = dataclasses.field(
+        default_factory=dict)
+
+
+def _trace(run: RunRecord) -> Optional[TraceSummary]:
+    t = run.trace
+    return t if t is not None and t.readable else None
+
+
+def traced_work(run: RunRecord, kernel: str):
+    """(flops, bytes) of the kernel's work in the calls the trace covers:
+    the whole window's on a complete trace, the covered calls' on a
+    truncated one; None where that is not known."""
+    t = _trace(run)
+    if t is None:
+        return None
+    if not t.truncated:
+        return run.work.get(kernel)
+    if kernel not in run.call_work:
+        return None
+    flops, nbytes = run.call_work[kernel]
+    return flops * t.covered_calls, nbytes * t.covered_calls
 
 
 def idle_share(run: RunRecord) -> Optional[float]:
-    if run.trace is None:
+    t = _trace(run)
+    if t is None:
         return None
-    return 100.0 * (1.0 - run.trace.busy_s / run.trace.window_s)
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
 
 
 def roofline_share(run: RunRecord, kernel: str) -> Optional[float]:
     """Least time the chip could take for the kernel's work over the
-    kernel's device time in the trace, in %; None when the kernel did not
-    run (a share is never reported as 0)."""
-    if run.trace is None or kernel not in run.work:
+    kernel's device time in the trace, in %, both over the calls the trace
+    covers; None when the kernel did not run (a share is never reported
+    as 0) or its work there is not known."""
+    work = traced_work(run, kernel)
+    if work is None:
         return None
     seconds = run.trace.kernel_seconds(KERNEL_OPS[kernel])
     if seconds <= 0:
         return None
-    least, _ = least_time_s(*run.work[kernel], run.peaks)
+    least, _ = least_time_s(*work, run.peaks)
     return 100.0 * least / seconds
 
 

@@ -19,6 +19,11 @@ stamped with the return of the ``step()`` that delivered it, and the frame
 gaps are the differences between a request's consecutive stamps, the
 prompt outputs first.
 
+Each engine step runs in a ``bench.step`` span, the unit a truncated
+trace is counted in (``harness/trace.py``).  Steps differ in their work,
+so on a truncated trace the kernels' roofline shares read nothing; the
+idle share covers the covered steps.
+
 Correctness: a sample of finished requests drawn from the seed, the
 longest request among them, is compared teacher-forced with the reference
 (``harness.check``).  A request that failed, a degraded launch or a
@@ -142,9 +147,9 @@ def run(cell, seed: int, seconds: float, profile_dir, devices, compiles,
     setup_s = now() - clock0
 
     obs = Observer()
-    tick_ms, late_ms, queue_at_last = [], [], 0
+    tick_ms, late_ms, queue_at_last, steps = [], [], 0, 0
     n, nxt = len(reqs), 0
-    with profiled(profile_dir) as tr:
+    with profiled(profile_dir, call="bench.step") as tr:
         with annotate("bench.window"):
             t0 = now()
             while True:
@@ -166,6 +171,7 @@ def run(cell, seed: int, seconds: float, profile_dir, devices, compiles,
                         a = now()
                         engine.step()
                         b = now()
+                    steps += 1
                     if engine.prefill_waves == waves:
                         tick_ms.append(1e3 * (b - a))
                     with annotate("bench.observe"):
@@ -186,6 +192,7 @@ def run(cell, seed: int, seconds: float, profile_dir, devices, compiles,
                  "faults_total")}
     counters.update({k: getattr(engine, k) - e0[k] for k in e0})
     counters["compiles"] = compiles.snapshot() - c0
+    counters["calls"] = steps
     del engine
 
     # ---- end-to-end metrics over every request due in the window

@@ -14,13 +14,16 @@ one trace, clipped to the ``bench.window`` annotation, to:
   ``repro.*`` span open at each gap's midpoint (``bench.window`` when none
   is), as ``trace.py`` charges them to ``bench.*`` spans alone.
 
+On a truncated device record the window ends with the last call it
+covers, as in ``trace.py``.
+
 Busy time, kernel time and the idle share stay with ``trace.py``.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from bench.harness import trace
 
@@ -83,13 +86,14 @@ def self_times(spans, w0: float, w1: float):
     return dict(total), dict(own), dict(counts)
 
 
-def reduce_planes(planes, window: str = trace.WINDOW) -> Phases:
+def reduce_planes(planes, window: str = trace.WINDOW,
+                  call: Optional[str] = None) -> Phases:
+    """Over the window of ``trace.window_of``: on a truncated device
+    record, the calls it covers."""
     planes = list(planes)
     spans = host_spans(planes)
-    windows = [(a, b) for a, b, n, _ in spans if n == window]
-    if not windows:
-        raise ValueError(f"trace has no {window!r} host annotation")
-    w0, w1 = min(a for a, _ in windows), max(b for _, b in windows)
+    win = trace.window_of(planes, spans, window, call)
+    w0, w1 = win.start_ns, win.end_ns
     inner = sorted(s for s in spans
                    if s[2] != window and s[1] > w0 and s[0] < w1)
     seconds, own, counts = self_times(inner, w0, w1)

@@ -8,6 +8,17 @@ time and idle gaps attributed to what the host was doing.
   Pallas kernel is found by the name of the jitted function that wraps it.
 * The window is the host annotation ``bench.window`` that the drivers
   open around the measured loop; everything is clipped to it.
+* The profiler's device buffer holds a few million events; a program that
+  issues more in the window loses the rest, and the device plane then
+  carries a ``dropped_traces`` stat above 0.  On such a truncated record
+  the window ends instead with the last *covered* call: a call is the
+  host span a driver names as one unit of its work (``bench.forward``,
+  ``bench.step``), and it is covered when it ends by the record's end,
+  the latest end of a device operation (the least over the device
+  planes).  Every reading then covers the same calls, and the driver
+  counts the work of those alone.  A complete record keeps the whole
+  window: the last call's span ends just after its own last operation,
+  so clipping there would drop that call.
 * Busy time is the union of the operation intervals (averaged over the
   device planes); idle is the window less that.
 * Each idle gap is charged to the innermost ``bench.*`` host annotation
@@ -25,6 +36,7 @@ from typing import Dict, List, Optional, Tuple
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
+DROPPED = "dropped_traces"
 WINDOW = "bench.window"
 PREFIX = "bench."
 _SUFFIX = re.compile(r"\.\d+$")
@@ -39,6 +51,15 @@ class TraceSummary:
     op_counts: Dict[str, int]
     gap_seconds: Dict[str, float]       # idle time by host annotation
     longest_gaps: List[Tuple[str, float]]
+    truncated: bool = False             # the device record was cut short
+    dropped_traces: int = 0             # the device planes' stat, summed
+    covered_calls: int = 0              # call spans the window holds
+
+    @property
+    def readable(self) -> bool:
+        """False for a truncated record that holds no whole call: then
+        no reading of it covers any work."""
+        return not self.truncated or self.covered_calls > 0
 
     def kernel_seconds(self, op: str) -> float:
         """Device time of the operations keyed ``op`` (``%lstm_seq``)."""
@@ -98,16 +119,65 @@ def _attribute(spans, mids: List[float]) -> List[Optional[str]]:
     return out
 
 
-def reduce_planes(planes, window: str = WINDOW) -> TraceSummary:
-    """Reduce the planes of one ``jax.profiler.ProfileData``; the window
-    runs from the first ``window`` host span's start to the last one's
-    end."""
-    planes = list(planes)
-    spans = _host_spans(planes)
-    windows = [(a, b) for a, b, n in spans if n == window]
+def _dropped(planes) -> int:
+    """The device planes' ``dropped_traces``; planes without stats (as
+    synthetic ones are) drop nothing."""
+    return sum(int(v) for p in planes if DEVICE_PLANE.match(p.name)
+               for k, v in getattr(p, "stats", ()) if k == DROPPED)
+
+
+def _record_end(planes) -> float:
+    """The latest end of a device operation, the least over the device
+    planes that hold any (``-inf`` when none does)."""
+    ends = [max(e.start_ns + e.duration_ns for e in line.events)
+            for p in planes if DEVICE_PLANE.match(p.name)
+            for line in p.lines if line.name == OPS_LINE and line.events]
+    return min(ends, default=float("-inf"))
+
+
+@dataclasses.dataclass(frozen=True)
+class Window:
+    start_ns: float
+    end_ns: float
+    truncated: bool
+    dropped_traces: int
+    covered_calls: int
+
+
+def window_of(planes, spans, window: str = WINDOW,
+              call: Optional[str] = None) -> Window:
+    """The window every reading covers.  ``spans`` are host spans, each
+    ``(start_ns, end_ns, name, ...)``; the window runs from the first
+    ``window`` span's start to the last one's end, and on a truncated
+    record to the end of the last ``call`` span the record covers
+    (``start_ns`` itself when none is, or no ``call`` is named)."""
+    windows = [(s[0], s[1]) for s in spans if s[2] == window]
     if not windows:
         raise ValueError(f"trace has no {window!r} host annotation")
     w0, w1 = min(a for a, _ in windows), max(b for _, b in windows)
+    ends = [s[1] for s in spans if s[2] == call and s[1] > w0 and s[0] < w1]
+    dropped = _dropped(planes)
+    if not dropped:
+        return Window(w0, w1, False, 0, len(ends))
+    record_end = _record_end(planes)
+    covered = [b for b in ends if b <= record_end]
+    return Window(w0, min(max(covered, default=w0), w1), True, dropped,
+                  len(covered))
+
+
+def reduce_planes(planes, window: str = WINDOW,
+                  call: Optional[str] = None) -> TraceSummary:
+    """Reduce the planes of one ``jax.profiler.ProfileData`` over the
+    window of ``window_of``; ``call`` names the host span of one unit of
+    the driver's work."""
+    planes = list(planes)
+    spans = _host_spans(planes)
+    win = window_of(planes, spans, window, call)
+    w0, w1 = win.start_ns, win.end_ns
+    cover = dict(truncated=win.truncated, dropped_traces=win.dropped_traces,
+                 covered_calls=win.covered_calls)
+    if not win.covered_calls and win.truncated:
+        return TraceSummary(0.0, 0.0, 0, {}, {}, {}, [], **cover)
     inner = [s for s in spans if s[2] != window and s[1] > w0 and s[0] < w1]
     inner.sort()
 
@@ -153,11 +223,12 @@ def reduce_planes(planes, window: str = WINDOW) -> TraceSummary:
         n_devices=n_dev,
         op_seconds={k: v / n_dev / 1e9 for k, v in op_ns.items()},
         op_counts=dict(op_n), gap_seconds=dict(gaps),
-        longest_gaps=longest[:10])
+        longest_gaps=longest[:10], **cover)
 
 
-def reduce_file(path, window: str = WINDOW) -> TraceSummary:
+def reduce_file(path, window: str = WINDOW,
+                call: Optional[str] = None) -> TraceSummary:
     import jax
 
     return reduce_planes(
-        jax.profiler.ProfileData.from_file(str(path)).planes, window)
+        jax.profiler.ProfileData.from_file(str(path)).planes, window, call)

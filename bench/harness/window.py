@@ -47,9 +47,11 @@ def annotate(name: str):
 
 
 @contextlib.contextmanager
-def profiled(directory: Optional[Path]):
+def profiled(directory: Optional[Path], call: str):
     """Trace the enclosed window into ``directory`` when given; yields a
-    holder whose ``summary`` is set once the trace is reduced."""
+    holder whose ``summary`` is set once the trace is reduced.  ``call``
+    names the host span of one unit of the driver's work
+    (``trace.window_of``)."""
     holder = type("Trace", (), {"summary": None})()
     if directory is None:
         yield holder
@@ -66,7 +68,7 @@ def profiled(directory: Optional[Path]):
     files = sorted(glob.glob(str(directory / "plugins/profile/*/*.xplane.pb")))
     if not files:
         raise RuntimeError(f"profiler wrote no trace under {directory}")
-    holder.summary: TraceSummary = reduce_file(files[-1])
+    holder.summary: TraceSummary = reduce_file(files[-1], call=call)
     shutil.rmtree(directory, ignore_errors=True)
 
 

@@ -80,8 +80,12 @@ def profiled_loop(cs, seed: int, in_shape, seconds: float,
     (path,) = glob.glob(str(directory / "plugins/profile/*/*.xplane.pb"))
     planes = list(jax.profiler.ProfileData.from_file(path).planes)
     shutil.rmtree(directory, ignore_errors=True)
-    dev, ph = trace.reduce_planes(planes), spans.reduce_planes(planes)
-    calls = out["calls"]
+    dev = trace.reduce_planes(planes, call="bench.forward")
+    if not dev.readable:
+        raise RuntimeError("the device record covers no whole call")
+    ph = spans.reduce_planes(planes, call="bench.forward")
+    # per call of those the device record covers (all, unless truncated)
+    calls = dev.covered_calls
 
     def per_call(d):
         return {k: v * 1e3 / calls for k, v in sorted(d.items())}
@@ -90,6 +94,7 @@ def profiled_loop(cs, seed: int, in_shape, seconds: float,
               if k.startswith(spans.PHASE)}
     forward_s = ph.span_seconds.get("bench.forward", 0.0)
     out.update(
+        trace_calls=calls, dropped_traces=dev.dropped_traces,
         busy_s=dev.busy_s, traced_window_s=dev.window_s,
         idle_share=100.0 * (1.0 - dev.busy_s / dev.window_s),
         self_ms_per_call=per_call(phases),

@@ -8,7 +8,9 @@ from the seed, compilation, warm-up of the cell's own shapes) counts as
 ``setup_s``; then the window runs for ``--seconds``.  With ``--trace 0``
 the result carries the cell's end-to-end metrics; with ``--trace 1`` the
 window runs under the profiler and the result carries its per-layer
-metrics, the device's busy time and a breakdown.
+metrics, the device's busy time and a breakdown, all over the calls the
+device record covers: ``device`` gives their count (``trace_calls``)
+beside the window's (``calls``) and the profiler's ``dropped_traces``.
 
 Every run checks what the timed path produced against the plain reference
 (``bench/reference``) and prints each number compared beside its limit,
@@ -78,9 +80,15 @@ def result_line(cell, out, devices, trace: bool) -> dict:
     line = {"correct": out.correct, "attempted": int(out.attempted),
             "failed": int(out.failed), "metrics": metrics, "device": dev}
     if trace and rec.trace is not None:
-        dev["busy_s"] = rec.trace.busy_s
-        dev["window_s"] = rec.trace.window_s
-        line["breakdown"] = rec.trace.breakdown()
+        t = rec.trace
+        if t.readable:
+            dev["busy_s"] = t.busy_s
+            dev["window_s"] = t.window_s
+            line["breakdown"] = t.breakdown()
+        # how much of the window the device record covers (trace.py)
+        dev.update(trace_calls=t.covered_calls,
+                   calls=int(rec.counters["calls"]),
+                   dropped_traces=t.dropped_traces)
     line["checks"] = {name: {"value": _number(v), "limit": lim}
                       for name, (v, lim) in out.checks.items()}
     return line

@@ -162,11 +162,6 @@ def test_cpu_capture_of_a_traced_forward(tmp_path):
     (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
     ph = spans.reduce_planes(
         jax.profiler.ProfileData.from_file(str(path)).planes)
-    n_slots = len(cs.plan.slots)
-    for name in ("repro.hoist", "repro.pack", "repro.slot_launch",
-                 "repro.scatter"):
-        assert ph.span_counts[name] == 2 * n_slots
-        assert ph.span_self_seconds[name] > 0
     assert ph.span_counts["repro.forward"] == 2
     own = sum(v for k, v in ph.span_self_seconds.items()
               if k.startswith(spans.PHASE))
