@@ -2,11 +2,12 @@
 
 See README.md in this directory for the mapping to SHARP §5–6.
 """
-from repro.dispatch.executor import execute, prepare_decode_stack
+from repro.dispatch.executor import (PlanProgram, execute,
+                                     prepare_decode_stack)
 from repro.dispatch.planner import (Cell, DispatchPlan, ItemPlan, Slot,
                                     plan, plan_decode)
 from repro.dispatch.workitem import WorkItem
 
-__all__ = ["WorkItem", "plan", "plan_decode", "execute",
+__all__ = ["WorkItem", "plan", "plan_decode", "execute", "PlanProgram",
            "prepare_decode_stack", "DispatchPlan", "ItemPlan", "Slot",
            "Cell"]

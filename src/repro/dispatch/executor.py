@@ -5,11 +5,21 @@ one G-batched sequence-fused kernel launch (kernels.lstm_cell.lstm_seq or
 kernels.gru_cell.gru_seq), with each cell's hoisted input GEMM issued in
 the same slot (no recurrent dependence, so it overlaps the serial tail —
 the paper's Fig. 8.d across items as well as layers).  Per-(item, layer)
-recurrent state lives in host-side arrays between slots and inside VMEM
+recurrent state lives in device arrays between slots and inside VMEM
 scratch within a launch; the final chunk of every layer is launched at its
 true remainder length (the kernels T-edge-mask internally), so the state
 left behind after the last slot is the exact t=T state — which is what the
 serving engine splices into its decode slots.
+
+One program per plan: ``PlanProgram`` traces the whole slot walk once
+into one ``jax.jit`` program (parameters and inputs are its arguments),
+so every later call at the plan's signature is one host dispatch instead
+of one per slice, flip, pad, stack, GEMM and scatter.  The walk below is
+the one body of code for both: the program runs it under the trace, and
+``execute`` runs it eagerly only as the fallback rung — without a
+program, under an armed ``FaultInjector``, or after the program failed.
+Per-slot trace spans therefore occur when a program is built, not on
+every call.
 
 Cross-B packing executes here too: a slot row may be several parameter-
 sharing cells' batches concatenated (same U — the WorkItem.share contract),
@@ -46,12 +56,17 @@ pre-ISSUE-6 fail-fast behaviour, wrapping the failure in a structured
 ``check_finite`` additionally verifies each launch's recurrent state and
 raises ``NonFiniteStateError`` naming exactly the poisoned items (a NaN is
 deterministic — no rung can fix it — so this raises under either mode).
+A program is the planned rung of every slot at once: under "fallback" a
+program that fails completes through the eager walk, whose slots keep the
+per-step and reference rungs.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.perfmodel import MXU_ROWS
 from repro.dispatch.planner import DispatchPlan, ItemPlan
@@ -80,7 +95,7 @@ def execute(plan: DispatchPlan, params: Dict[int, dict],
             collect_state: bool = False,
             init_state: Optional[Dict[int, dict]] = None,
             prepared: Optional[Dict[int, dict]] = None,
-            quant_cache: Optional[dict] = None,
+            program: Optional["PlanProgram"] = None,
             on_fault: str = "raise",
             check_finite: bool = False,
             inject: Optional[FaultInjector] = None,
@@ -99,6 +114,16 @@ def execute(plan: DispatchPlan, params: Dict[int, dict],
     decode state must check for a plain {"h": ...} dict, as the serving
     engine does.
 
+    ``program`` (a ``PlanProgram`` of this plan) runs the whole slot walk
+    as one compiled program: one host dispatch per call, traced and
+    compiled on its first call at a signature.  ``CompiledStack`` builds
+    one per cached plan.  Without it — and whenever an armed ``inject``
+    must fire per slot and per rung — the same walk runs eagerly, one op
+    at a time: the fallback rung.  Under ``on_fault="fallback"`` a program
+    that fails to trace, lower or run completes through the eager walk and
+    its ladder, recorded in ``report`` (``program_fallbacks``); under
+    "raise" the failure is a ``LaunchError``.
+
     ``init_state`` optionally seeds the recurrent state of packed items:
     init_state[uid] = {"h": (L,B,H)[, "c": (L,B,H)]} replaces the zero
     initial state (the serving engine's decode ticks resume from it).
@@ -111,13 +136,10 @@ def execute(plan: DispatchPlan, params: Dict[int, dict],
     (see ``prepare_decode_stack``) so steady-state decode ticks don't
     restack unchanged parameters every tick.
 
-    ``quant_cache`` memoizes per-(item, layer, direction) quantized /
-    row-compacted recurrent-weight operands across slots (and across
-    calls, when the caller owns the dict — ``CompiledStack`` keeps one per
-    plan, valid while the bound parameters don't change).  None builds a
-    per-call cache, so each layer is still transformed at most once per
-    execute().  Only consulted for slots whose ``precision != "fp32"`` or
-    whose items carry a block-sparsity ``tile_map``.
+    Slots whose ``precision != "fp32"`` or whose items carry a
+    block-sparsity ``tile_map`` quantize / row-compact their recurrent
+    weights inside the walk, once per (item, layer, direction) — in a
+    program, once per trace, as part of the compiled program.
 
     ``collect_state`` reroutes unpacked (external) unidirectional items
     through the per-layer fused path — the only surface that returns exact
@@ -130,7 +152,9 @@ def execute(plan: DispatchPlan, params: Dict[int, dict],
     recording each degradation in ``report``; "raise" fails fast with a
     structured ``LaunchError``.  ``check_finite`` raises
     ``NonFiniteStateError`` naming exactly the items whose post-launch
-    recurrent state went NaN/Inf.  ``inject`` is the test-time fault hook
+    recurrent state went NaN/Inf at the first slot where any did: the walk
+    returns one finiteness flag per (slot, item) and they are read once,
+    after the call.  ``inject`` is the test-time fault hook
     (``runtime.errors.FaultInjector``).
 
     ``tracer`` (optional ``runtime.obs.Tracer``): every packed/chained
@@ -138,13 +162,17 @@ def execute(plan: DispatchPlan, params: Dict[int, dict],
     ``hoist`` (each cell's source slice/flip/concat and input GEMM),
     ``pack`` (row concat + pad, the stacks, the slot's weight operands),
     ``slot_launch`` (the guarded launch, tagged with the slot signature
-    and uids) and ``scatter`` (the results split back per cell).  Spans
-    are host time and never fence; with ``ExecutionPolicy(trace=True)``
-    each is a ``repro.<name>`` annotation in a ``jax.profiler`` capture,
-    which holds the device time beside it.  Ladder recoveries appear as
-    nested ``fallback_rung`` spans and ``launch_fault`` instants.  None
-    (the default) binds the shared no-op tracer — no events, outputs
-    bit-identical.
+    and uids) and ``scatter`` (the results split back per cell).  Under a
+    ``program`` the walk runs only while the program is traced, so these
+    spans occur once per program, nested under the call that built it;
+    every call gets one ``program`` span around the program's host
+    dispatch.  Spans are host time and never fence; with
+    ``ExecutionPolicy(trace=True)`` each is a ``repro.<name>`` annotation
+    in a ``jax.profiler`` capture, which holds the device time beside it.
+    Ladder recoveries appear as nested ``fallback_rung`` spans and
+    ``launch_fault`` instants, a failed program as a ``program_fault``
+    instant.  None (the default) binds the shared no-op tracer — no
+    events, outputs bit-identical.
     """
     tracer = as_tracer(tracer)
     if on_fault not in ("raise", "fallback"):
@@ -168,11 +196,112 @@ def execute(plan: DispatchPlan, params: Dict[int, dict],
             f"init_state given for external-fallback items {dropped}: their "
             "schedule surfaces start from zero state — plan them onto the "
             "packed timeline (e.g. schedule='wavefront') to resume")
+    bidir = [ip.uid for ip in plan.items
+             if ip.item.bidirectional and ip.uid in (init_state or {})]
+    if bidir:
+        raise ValueError(
+            f"init_state given for bidirectional items {bidir}: the "
+            "fwd/bwd walks start from opposite sequence ends, so there "
+            "is no mid-stream state to resume from")
 
+    result = None
+    if program is not None and not (inject is not None and inject.armed):
+        result = _run_program(program, plan, params, inputs, init_state,
+                              prepared, collect_state=collect_state,
+                              check_finite=check_finite, on_fault=on_fault,
+                              report=report, tracer=tracer)
+    if result is None:
+        result = _walk(plan, params, inputs, init_state, prepared,
+                       interpret=interpret, collect_state=collect_state,
+                       check_finite=check_finite, on_fault=on_fault,
+                       inject=inject, report=report, tracer=tracer)
+    outputs, states, finite = result
+    if check_finite:
+        _raise_nonfinite(plan, finite)
+    return (outputs, states) if collect_state else outputs
+
+
+class PlanProgram:
+    """One plan's whole slot walk — hoist, pack, launch, scatter and the
+    final concat — as one ``jax.jit`` program.
+
+    The parameters, inputs, ``init_state`` and ``prepared`` decode weights
+    are arguments, never closed over, so the program holds no weights and
+    one compile serves every call at the plan's signature; whether each
+    optional operand is present, ``collect_state`` and ``check_finite``
+    are part of that signature.  Each signature traces the walk once
+    (``builds`` counts the traces); later calls are one dispatch.  The
+    program's ladder is the planned rung alone: a launch that fails to
+    trace raises out of the program, and ``execute`` decides between
+    ``LaunchError`` and the eager walk's full ladder."""
+
+    def __init__(self, plan: DispatchPlan, *,
+                 interpret: Optional[bool] = None, tracer=None):
+        self.plan = plan
+        self.builds = 0
+        tracer = as_tracer(tracer)
+
+        def plan_program(params, inputs, init_state, prepared, *,
+                         collect_state: bool, check_finite: bool):
+            out = _walk(plan, params, inputs, init_state, prepared,
+                        interpret=interpret, collect_state=collect_state,
+                        check_finite=check_finite, on_fault="raise",
+                        inject=None, report=None, tracer=tracer)
+            self.builds += 1
+            return out
+
+        self._jit = jax.jit(plan_program,
+                            static_argnames=("collect_state", "check_finite"))
+
+    def __call__(self, params, inputs, init_state=None, prepared=None, *,
+                 collect_state: bool = False, check_finite: bool = False):
+        """(outputs, states, finiteness flags) — ``execute``'s walk."""
+        return self._jit(params, inputs, init_state, prepared,
+                         collect_state=collect_state,
+                         check_finite=check_finite)
+
+    def lower(self, params, inputs, init_state=None, prepared=None, *,
+              collect_state: bool = False, check_finite: bool = False):
+        """``jax.jit(...).lower`` of the program — ahead-of-time compiles
+        take shapes (``jax.ShapeDtypeStruct``) in place of arrays."""
+        return self._jit.lower(params, inputs, init_state, prepared,
+                               collect_state=collect_state,
+                               check_finite=check_finite)
+
+
+def _run_program(program, plan, params, inputs, init_state, prepared, *,
+                 collect_state, check_finite, on_fault, report, tracer):
+    """One call of ``program``; None when it failed under "fallback"."""
+    try:
+        with tracer.span("program", slots=len(plan.slots)):
+            return program(params, inputs, init_state, prepared,
+                           collect_state=collect_state,
+                           check_finite=check_finite)
+    except Exception as err:  # noqa: BLE001 — the program is a ladder rung
+        uids = sorted(ip.uid for ip in plan.items)
+        fault = err if isinstance(err, LaunchError) else LaunchError(
+            f"plan program failed ({len(plan.slots)} slots, uids {uids}): "
+            f"{err!r}", uids=uids, level=FALLBACK_LEVELS[0])
+        if tracer.enabled:
+            tracer.instant("program_fault", error=type(err).__name__)
+        if on_fault != "fallback":
+            if fault is err:
+                raise
+            raise fault from err
+        if report is not None:
+            report.record_program(fault)
+        return None
+
+
+def _walk(plan, params, inputs, init_state, prepared, *, interpret,
+          collect_state, check_finite, on_fault, inject, report, tracer):
+    """THE slot walk, eager or under a ``PlanProgram``'s trace: returns
+    (outputs, states, finite), ``finite`` {(slot index, uid): bool array}
+    when ``check_finite`` (else empty)."""
     outputs: Dict[int, jnp.ndarray] = {}
     states: Dict[int, dict] = {}
-    if quant_cache is None:
-        quant_cache = {}  # per-call memo: each layer transforms at most once
+    finite: Dict[Tuple[int, int], jnp.ndarray] = {}
+    weights: dict = {}  # per-walk memo: each layer transforms at most once
 
     # ---- external fallbacks (reference schedules / per-step / rglru /
     # T=0) — bidirectional items land here only under a forced stateless
@@ -216,11 +345,6 @@ def execute(plan: DispatchPlan, params: Dict[int, dict],
         dirs = ("fwd", "bwd") if it.bidirectional else ("fwd",)
         dtype = inputs[it.uid].dtype
         st0 = (init_state or {}).get(it.uid)
-        if st0 is not None and it.bidirectional:
-            raise ValueError(
-                f"init_state given for bidirectional item {it.uid}: the "
-                "fwd/bwd walks start from opposite sequence ends, so there "
-                "is no mid-stream state to resume from")
 
         def _c0(l):
             # cell state exists per LSTM layer only; a mixed stack's gru
@@ -244,7 +368,7 @@ def execute(plan: DispatchPlan, params: Dict[int, dict],
 
     for slot in plan.slots:
         if slot.chained:
-            _run_chained_slot(slot, params, inputs, live,
+            _run_chained_slot(slot, params, inputs, live, finite,
                               interpret=interpret, prepared=prepared,
                               on_fault=on_fault, check_finite=check_finite,
                               inject=inject, report=report,
@@ -267,8 +391,7 @@ def execute(plan: DispatchPlan, params: Dict[int, dict],
                             for rows in xw_rows])   # (G, B, bt, gates, H)
             h0 = _state_rows(slot, live, "h")       # (G, B, H)
             c0 = _state_rows(slot, live, "c") if lstm else None
-            U, u_scales, u_rows = _slot_weights(slot, params, live,
-                                                quant_cache)
+            U, u_scales, u_rows = _slot_weights(slot, params, live, weights)
             b_valid = (jnp.asarray(slot.group_b, jnp.int32)
                        if any(b < slot.B for b in slot.group_b) else None)
             uids = sorted({c.uid for grp in slot.groups for c in grp})
@@ -283,7 +406,6 @@ def execute(plan: DispatchPlan, params: Dict[int, dict],
                 on_fault=on_fault, inject=inject, report=report,
                 tracer=tracer)
         with tracer.span("scatter", slot=slot.index):
-            bad: List[int] = []
             for g, grp in enumerate(slot.groups):
                 off = 0
                 for cell in grp:
@@ -293,10 +415,10 @@ def execute(plan: DispatchPlan, params: Dict[int, dict],
                     st["h"][key] = h_n[g, off:off + nb].astype(h0.dtype)
                     if c_n is not None:
                         st["c"][key] = c_n[g, off:off + nb]
-                    if check_finite and not _rows_finite(
-                            h_n[g, off:off + nb],
-                            None if c_n is None else c_n[g, off:off + nb]):
-                        bad.append(cell.uid)
+                    if check_finite:
+                        _flag(finite, slot.index, cell.uid,
+                              h_n[g, off:off + nb],
+                              None if c_n is None else c_n[g, off:off + nb])
                     chunk = out[g, off:off + nb].astype(
                         inputs[cell.uid].dtype)
                     if cell.direction == "bwd":
@@ -305,12 +427,6 @@ def execute(plan: DispatchPlan, params: Dict[int, dict],
                         chunk = jnp.flip(chunk, axis=1)
                     st["outs"][key][cell.chunk] = chunk
                     off += nb
-            if bad:
-                bad = sorted(set(bad))
-                raise NonFiniteStateError(
-                    f"non-finite recurrent state after slot {slot.index} "
-                    f"(uids {bad})", uids=bad, slot=slot.index,
-                    where="slot state")
 
     for uid, st in live.items():
         it = st["plan"].item
@@ -328,7 +444,7 @@ def execute(plan: DispatchPlan, params: Dict[int, dict],
             else:
                 states[uid] = _dir_state(st, it, "fwd")
 
-    return (outputs, states) if collect_state else outputs
+    return outputs, states, finite
 
 
 def _state_rows(slot, live, part: str):
@@ -340,7 +456,7 @@ def _state_rows(slot, live, part: str):
                       for grp in slot.groups])
 
 
-def _slot_weights(slot, params, live, cache: dict):
+def _slot_weights(slot, params, live, memo: dict):
     """Stack one sequence slot's per-group recurrent-weight operands under
     the slot's precision and its items' block-sparsity tile maps.
 
@@ -350,8 +466,9 @@ def _slot_weights(slot, params, live, cache: dict):
     payload plus ``u_scales (G, gates)``; a tile_map row-compacts to the
     slot-uniform ``Ha`` active-row count plus ``u_rows (G, Ha)``.  Groups
     without a tile_map in a sparse slot ride along dense (all-ones bitmap).
-    Per-(item, layer, direction) transforms memoize in ``cache`` so the
-    chunk slots of one layer quantize/compact the weights ONCE per plan.
+    Per-(item, layer, direction) transforms memoize in ``memo`` (one walk's)
+    so the chunk slots of one layer quantize/compact the weights ONCE per
+    walk — under a ``PlanProgram``, once per trace.
     """
     gates = GATES[slot.family]
     leads = [grp[0] for grp in slot.groups]
@@ -376,7 +493,7 @@ def _slot_weights(slot, params, live, cache: dict):
     for cell in leads:
         key = (cell.uid, cell.layer, cell.direction, slot.precision,
                Ha if sparse else -1)
-        entry = cache.get(key)
+        entry = memo.get(key)
         if entry is None:
             U = _cell_layer_params(params, live[cell.uid], cell)["U"] \
                 .reshape(slot.H, gates, slot.H)
@@ -388,7 +505,7 @@ def _slot_weights(slot, params, live, cache: dict):
             r = None
             if sparse:
                 U, r = compact_rows(U, _bitmap(cell), pad_to=Ha)
-            entry = cache[key] = (U, s, r)
+            entry = memo[key] = (U, s, r)
         us.append(entry[0])
         scales.append(entry[1])
         rows.append(entry[2])
@@ -515,12 +632,37 @@ def _seq_ladder(slot, U, xw, h0, c0, b_valid, *, u_scales=None, u_rows=None,
     return [fused, per_step, reference]
 
 
-def _rows_finite(h_rows, c_rows=None) -> bool:
-    """True when one cell's slice of post-launch state is all-finite."""
-    ok = bool(jnp.isfinite(h_rows).all())
-    if ok and c_rows is not None:
-        ok = bool(jnp.isfinite(c_rows).all())
-    return ok
+def _flag(finite: dict, slot_index: int, uid: int, h_rows,
+          c_rows=None) -> None:
+    """AND one cell's post-launch state finiteness into the (slot, uid)
+    flag — an array, so a program can return it (no ``bool()`` on a
+    tracer)."""
+    ok = jnp.isfinite(h_rows).all()
+    if c_rows is not None:
+        ok = ok & jnp.isfinite(c_rows).all()
+    key = (slot_index, uid)
+    finite[key] = ok if key not in finite else finite[key] & ok
+
+
+def _raise_nonfinite(plan: DispatchPlan, finite: dict) -> None:
+    """Read every flag in one transfer; raise ``NonFiniteStateError`` for
+    the first slot with a non-finite item, naming exactly its uids."""
+    if not finite:
+        return
+    keys = sorted(finite)
+    ok = np.asarray(jnp.stack([finite[k] for k in keys]))
+    bad = [k for k, good in zip(keys, ok) if not good]
+    if not bad:
+        return
+    index = bad[0][0]
+    uids = sorted(uid for s, uid in bad if s == index)
+    if next(s for s in plan.slots if s.index == index).chained:
+        raise NonFiniteStateError(
+            f"non-finite recurrent state after chained slot {index} "
+            f"(uids {uids})", uids=uids, slot=index, where="decode tick")
+    raise NonFiniteStateError(
+        f"non-finite recurrent state after slot {index} (uids {uids})",
+        uids=uids, slot=index, where="slot state")
 
 
 def _dir_state(st, item, direction: str) -> dict:
@@ -620,7 +762,8 @@ def prepare_decode_stack(stack_params: dict, family: str,
     }
 
 
-def _run_chained_slot(slot, params, inputs, live, *, interpret=None,
+def _run_chained_slot(slot, params, inputs, live, finite, *,
+                      interpret=None,
                       prepared=None, on_fault: str = "raise",
                       check_finite: bool = False,
                       inject: Optional[FaultInjector] = None,
@@ -674,15 +817,13 @@ def _run_chained_slot(slot, params, inputs, live, *, interpret=None,
 
     with tracer.span("scatter", slot=slot.index):
         off = 0
-        bad: List[int] = []
         for cell in row_cells:
             st = live[cell.uid]
             nb = st["plan"].item.B
             dtype = inputs[cell.uid].dtype
-            if check_finite and not _rows_finite(
-                    h_n[:, off:off + nb],
-                    None if c_n is None else c_n[:, off:off + nb]):
-                bad.append(cell.uid)
+            if check_finite:
+                _flag(finite, slot.index, cell.uid, h_n[:, off:off + nb],
+                      None if c_n is None else c_n[:, off:off + nb])
             for l in range(L):
                 st["h"][(l, "fwd")] = h_n[l, off:off + nb].astype(h0.dtype)
                 if c_n is not None:
@@ -691,12 +832,6 @@ def _run_chained_slot(slot, params, inputs, live, *, interpret=None,
                 st["outs"][(l, "fwd")][0] = \
                     h_n[l, off:off + nb, None].astype(dtype)
             off += nb
-        if bad:
-            bad = sorted(set(bad))
-            raise NonFiniteStateError(
-                f"non-finite recurrent state after chained slot "
-                f"{slot.index} (uids {bad})", uids=bad, slot=slot.index,
-                where="decode tick")
 
 
 def _chained_ladder(slot, xw0, Ws, bs, Us, h0, c0, *, interpret):

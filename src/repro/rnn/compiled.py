@@ -20,10 +20,14 @@ dispatcher's planner/executor:
     stats                launches / est_cycles / plans_built accounting
 
 Plans are shape-only and cached per (direction, B, T, dtype) signature, so
-repeated calls at one shape replan nothing — batch users, the serving
-engine, and the deprecated ``core.schedules.run_stack`` shim all share
-this exact pipeline, which is the point: dispatcher wins (wavefront
-packing, cross-B merges, chained decode) reach every entry surface, a
+repeated calls at one shape replan nothing.  Each cached plan carries its
+``dispatch.PlanProgram``: the plan's whole slot walk as one jitted
+program, traced and compiled on the plan's first call and then run as one
+dispatch per call; it lives and is evicted with its plan.  Batch users,
+the serving engine, and the deprecated ``core.schedules.run_stack`` shim
+all share this exact pipeline, which is the point: dispatcher wins
+(wavefront packing, cross-B merges, chained decode) reach every entry
+surface, a
 mixed lstm/gru stack wavefronts across families with no special casing
 (the planner groups cells into launches by their own layer's family), and
 a bidirectional stack runs the interleaved fwd/bwd wavefront (ISSUE-5) —
@@ -33,15 +37,15 @@ end-of-walk state, and decode raises (no streaming decode exists).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple, Union
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.schedules import stack_families
-from repro.dispatch import (DispatchPlan, WorkItem, execute, plan,
-                            plan_decode, prepare_decode_stack)
+from repro.dispatch import (DispatchPlan, PlanProgram, WorkItem, execute,
+                            plan, plan_decode, prepare_decode_stack)
 from repro.rnn.policy import ExecutionPolicy
 from repro.runtime.errors import ExecutionReport, FaultInjector
 from repro.runtime.obs import NULL_TRACER, Tracer
@@ -51,16 +55,21 @@ from repro.runtime.obs import NULL_TRACER, Tracer
 class StackStats:
     """Execution accounting of one CompiledStack (all counters cumulative).
 
-    ``launches``/``est_cycles`` include decode ticks; ``plans_built``
-    counts plan-cache misses (flat counters across steady-state reuse are
-    the plan-cache proof the serving tests assert).
+    ``launches``/``est_cycles`` include decode ticks (``launches`` counts
+    kernel launches, the plans' slots, whether a plan runs as one program
+    or eagerly); ``plans_built`` counts plan-cache misses (flat counters
+    across steady-state reuse are the plan-cache proof the serving tests
+    assert); ``programs_built`` counts plan programs traced and compiled —
+    flat across calls at a cached signature.
 
     ``degraded_launches`` counts slots the guarded execution ladder had to
     re-execute below their planned rung (policy ``on_fault="fallback"``);
     ``fallback_level`` is the deepest rung ever used (index into
     ``runtime.errors.FALLBACK_LEVELS``: 0 planned, 1 per-step, 2 pure-jnp
-    reference); ``faults`` is the human-readable fault trail — a ring
-    buffer keeping the ``MAX_FAULT_TRAIL`` most recent entries
+    reference); ``program_fallbacks`` counts calls whose plan program
+    failed and that the eager walk completed; ``faults`` is the
+    human-readable fault trail — a ring buffer keeping the
+    ``MAX_FAULT_TRAIL`` most recent entries
     (``faults_total`` counts every fault ever, so a long-lived serving
     stack under chronic degradation holds bounded memory without losing
     the signal).  All of these stay zero/empty on a healthy stack — they
@@ -83,10 +92,12 @@ class StackStats:
     est_cycles: float = 0.0
     plans_built: int = 0
     plans_verified: int = 0
+    programs_built: int = 0
     decode_launches: int = 0
     decode_plans_built: int = 0
     degraded_launches: int = 0
     fallback_level: int = 0
+    program_fallbacks: int = 0
     faults: List[str] = dataclasses.field(default_factory=list)
     faults_total: int = 0
     measured_hits: int = 0
@@ -236,14 +247,9 @@ class CompiledStack:
         if policy.sparsity == "block":
             from repro.kernels.quant import stack_tile_maps
             self._tile_map = stack_tile_maps(params)
-        #: per-plan memo of quantized / row-compacted weight operands —
-        #: valid for this stack's lifetime (the bound parameters never
-        #: change), so each layer quantizes at most once across every
-        #: forward/prefill/decode call
-        self._quant_cache: dict = {}
         self.last_decode_plan: Optional[DispatchPlan] = None
         self._last_plan: Optional[DispatchPlan] = None
-        self._plans: Dict[tuple, DispatchPlan] = {}
+        self._plans: Dict[tuple, PlanProgram] = {}  # each plan's program
         self._prepared: Optional[dict] = None
 
     # ------------------------------------------------------------------
@@ -281,9 +287,9 @@ class CompiledStack:
     #: is an unbounded leak.  LRU: re-hits refresh recency.
     MAX_CACHED_PLANS = 128
 
-    def _cached(self, key, build) -> DispatchPlan:
-        p = self._plans.get(key)
-        if p is None:
+    def _cached(self, key, build) -> PlanProgram:
+        entry = self._plans.get(key)
+        if entry is None:
             p = build()
             if self.policy.verify == "plan":
                 # verify ONCE per cache miss, before the plan is ever
@@ -294,8 +300,10 @@ class CompiledStack:
                     check_plan(p)
                 self.stats.plans_verified += 1
             while len(self._plans) >= self.MAX_CACHED_PLANS:
+                # the evicted plan's program, and its executables, go too
                 self._plans.pop(next(iter(self._plans)))
-            self._plans[key] = p
+            entry = self._plans[key] = PlanProgram(
+                p, interpret=self.policy.interpret, tracer=self.tracer)
             self.stats.plans_built += 1
             if key[0] == "dec":
                 self.stats.decode_plans_built += 1
@@ -305,17 +313,17 @@ class CompiledStack:
                 self.stats.analytic_fallbacks = cm.fallbacks
         else:
             self._plans[key] = self._plans.pop(key)  # LRU refresh
-        return p
+        return entry
 
     def lower(self, B: int, T: int, dtype: str = "float32",
               priority: int = 0) -> DispatchPlan:
         """Build (or fetch) the DispatchPlan for a shape without executing
         — the introspection entry point (``lower(...).describe()``).
         Shares its cache key with forward() and single-request prefill()."""
-        return self._lower_many(((B, T, dtype),), (priority,))
+        return self._lower_many(((B, T, dtype),), (priority,)).plan
 
     def _lower_many(self, shapes: Tuple[Tuple[int, int, str], ...],
-                    prios: Tuple[int, ...]) -> DispatchPlan:
+                    prios: Tuple[int, ...]) -> PlanProgram:
         """One plan over per-request (B, T, dtype) signatures — the single
         cache-key shape every entry point funnels through (a lone request
         and a one-element admission wave are the same plan)."""
@@ -343,24 +351,36 @@ class CompiledStack:
             xs = xs.astype(self.policy.dtype)
         return xs, squeeze
 
-    def _guard(self) -> Tuple[ExecutionReport, dict]:
-        """Per-call guarded-ladder kwargs for execute(): the policy's fault
-        knobs, this stack's injector, and a fresh degradation report that
-        ``_account`` folds into ``.stats`` after a successful call."""
+    def _execute(self, entry: PlanProgram, params: dict, inputs: dict, *,
+                 decode: bool = False, **kw):
+        """execute() one cached plan through its program, with the
+        policy's fault knobs, this stack's injector and tracer, and a fresh
+        degradation report that ``_account`` folds into ``.stats`` after a
+        successful call."""
         rep = ExecutionReport()
-        return rep, {"on_fault": self.policy.on_fault,
-                     "check_finite": self.policy.check_finite,
-                     "inject": self.fault, "report": rep,
-                     "tracer": self.tracer}
+        built = entry.builds
+        try:
+            out = execute(entry.plan, params, inputs,
+                          interpret=self.policy.interpret,
+                          program=entry,
+                          on_fault=self.policy.on_fault,
+                          check_finite=self.policy.check_finite,
+                          inject=self.fault, report=rep,
+                          tracer=self.tracer, **kw)
+        finally:
+            self.stats.programs_built += entry.builds - built
+        self._account(entry.plan, decode=decode, report=rep)
+        return out
 
     def _account(self, p: DispatchPlan, decode: bool = False,
                  report: Optional[ExecutionReport] = None) -> None:
         self.stats.launches += p.launches
         self.stats.est_cycles += p.est_cycles
-        if report is not None and report.degraded_launches:
+        if report is not None and report.faults:
             self.stats.degraded_launches += report.degraded_launches
             self.stats.fallback_level = max(self.stats.fallback_level,
                                             report.fallback_level)
+            self.stats.program_fallbacks += report.program_fallbacks
             self.stats.record_faults(report.faults)
         if decode:
             self.stats.decode_calls += 1
@@ -380,14 +400,11 @@ class CompiledStack:
             B, T, _ = xs.shape
             if T == 0:
                 raise ValueError("CompiledStack.forward: T=0 sequence")
-            p = self.lower(B, T, str(xs.dtype))
-            rep, guard = self._guard()
-            outs = execute(p, {0: self.params}, {0: xs},
-                           interpret=self.policy.interpret,
-                           quant_cache=self._quant_cache, **guard)
+            entry = self._lower_many(((B, T, str(xs.dtype)),), (0,))
+            outs = self._execute(entry, {0: self.params}, {0: xs})
             if tr.enabled:
+                p = entry.plan
                 sp.tag(B=B, T=T, plan=tr.plan_id(p), launches=p.launches)
-            self._account(p, report=rep)
         ys = outs[0]
         return ys[0] if squeeze else ys
 
@@ -433,18 +450,15 @@ class CompiledStack:
                 raise ValueError("CompiledStack.prefill: T=0 sequence")
             # per-request dtype: a mixed-precision wave must not share
             # launch signatures (the planner keys slots on dtype per item)
-            p = self._lower_many(
+            entry = self._lower_many(
                 tuple((x.shape[0], x.shape[1], str(x.dtype))
                       for x in inputs.values()), tuple(prios))
-            rep, guard = self._guard()
-            outs, states = execute(p, {i: self.params for i in inputs},
-                                   inputs,
-                                   interpret=self.policy.interpret,
-                                   collect_state=True,
-                                   quant_cache=self._quant_cache, **guard)
+            outs, states = self._execute(
+                entry, {i: self.params for i in inputs}, inputs,
+                collect_state=True)
             if tr.enabled:
-                sp.tag(plan=tr.plan_id(p), launches=p.launches)
-            self._account(p, report=rep)
+                sp.tag(plan=tr.plan_id(entry.plan),
+                       launches=entry.plan.launches)
         res = []
         for i, (_, squeeze) in enumerate(prepped):
             ys = outs[i][0] if squeeze else outs[i]
@@ -489,10 +503,10 @@ class CompiledStack:
         with tr.span("decode_tick", B=B) as sp:
             if not self.heterogeneous:
                 key = ("dec", B, dtype)
-                p = self._cached(key, lambda: plan_decode(
+                entry = self._cached(key, lambda: plan_decode(
                     [self._item(0, B, 1, dtype)], macs=self.policy.macs,
                     tracer=tr, cost_model=self.cost_model))
-                if p.items[0].schedule == "decode":
+                if entry.plan.items[0].schedule == "decode":
                     if self._prepared is None:
                         # self.params already carries the fake-quant view,
                         # so the precision round-trip here is an exact
@@ -517,21 +531,18 @@ class CompiledStack:
                 # per_step pick would route external, where execute()
                 # rejects init_state
                 key = ("dec", B, dtype)
-                p = self._cached(key, lambda: plan(
+                entry = self._cached(key, lambda: plan(
                     [self._item(0, B, 1, dtype)], macs=self.policy.macs,
                     cross_b=self.policy.packing, schedule="wavefront",
                     block_t=1, tracer=tr, cost_model=self.cost_model))
                 prepared = None
-            rep, guard = self._guard()
-            outs, states = execute(p, {0: self.params}, {0: x_t},
-                                   interpret=self.policy.interpret,
-                                   collect_state=True,
-                                   init_state={0: state},
-                                   prepared=prepared,
-                                   quant_cache=self._quant_cache, **guard)
+            outs, states = self._execute(
+                entry, {0: self.params}, {0: x_t}, decode=True,
+                collect_state=True, init_state={0: state},
+                prepared=prepared)
             if tr.enabled:
-                sp.tag(plan=tr.plan_id(p), launches=p.launches)
-            self._account(p, decode=True, report=rep)
+                sp.tag(plan=tr.plan_id(entry.plan),
+                       launches=entry.plan.launches)
         if tr.enabled:
             tr.metrics.histogram("decode_tick_us").observe(sp.dur_us)
         return outs[0], states[0]
@@ -553,15 +564,17 @@ class CompiledStack:
             f"calls, {s.launches} launches ({s.decode_launches} decode), "
             f"{s.plans_built} plans built ({s.decode_plans_built} decode, "
             f"{s.plans_verified} verified), "
+            f"{s.programs_built} programs built, "
             f"est {s.est_cycles:.0f}cy",
             f"  plan cache: {len(self._plans)} shapes",
         ]
-        if s.degraded_launches:
+        if s.degraded_launches or s.program_fallbacks:
             from repro.runtime.errors import FALLBACK_LEVELS
             lines.append(
                 f"  DEGRADED: {s.degraded_launches} launches fell back "
-                f"(deepest rung: {FALLBACK_LEVELS[s.fallback_level]}; "
-                f"{s.faults_total} faults, trail keeps last "
+                f"(deepest rung: {FALLBACK_LEVELS[s.fallback_level]}), "
+                f"{s.program_fallbacks} programs fell back to the eager "
+                f"walk ({s.faults_total} faults, trail keeps last "
                 f"{s.MAX_FAULT_TRAIL})")
         if self.tracer.enabled:
             lines.append("  observability:")

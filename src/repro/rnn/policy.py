@@ -117,8 +117,10 @@ class ExecutionPolicy:
                ``artifacts/measured_costs.json``.  Only read when
                ``cost_model="measured"``.
     trace:     record host-time spans + metrics for every call, plan,
-               slot phase (hoist/pack/slot_launch/scatter) and decode
-               tick on ``CompiledStack.tracer`` (a ``runtime.obs.Tracer``);
+               program dispatch and decode tick, and each slot phase
+               (hoist/pack/slot_launch/scatter) of the call that builds a
+               plan's program, on ``CompiledStack.tracer`` (a
+               ``runtime.obs.Tracer``);
                each span is also a ``repro.<name>`` annotation, so a
                ``jax.profiler`` capture around the calls puts the host
                phases on the profiler's clock beside the device time.

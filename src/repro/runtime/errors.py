@@ -187,12 +187,19 @@ class ExecutionReport:
 
     ``degraded_launches`` counts slots that needed any fallback rung;
     ``fallback_level`` is the deepest rung used (index into
-    ``FALLBACK_LEVELS``); ``faults`` is the human-readable fault trail
-    (one entry per recovered launch failure)."""
+    ``FALLBACK_LEVELS``); ``program_fallbacks`` counts plan programs that
+    failed and were completed by the eager walk; ``faults`` is the
+    human-readable fault trail (one entry per recovered failure)."""
 
     degraded_launches: int = 0
     fallback_level: int = 0
+    program_fallbacks: int = 0
     faults: List[str] = field(default_factory=list)
+
+    def record_program(self, cause: Exception) -> None:
+        self.program_fallbacks += 1
+        self.faults.append(
+            f"plan program: fell back to the eager walk after {cause!r}")
 
     def record(self, slot_index: int, level: int, cause: Exception) -> None:
         self.degraded_launches += 1

@@ -126,13 +126,23 @@ def test_unfolded_hoists_input_gemm():
     outside the scan, while intergate multiplies W inside the loop."""
     params, xs = _mk(1, 8, 32)
     unf = jax.make_jaxpr(lambda p, x: sch.run_layer(p, x, "unfolded"))(params, xs)
+
+    def outer(jaxpr):
+        # the plan runs as one jitted program: see through the jit calls
+        for e in jaxpr.eqns:
+            if e.primitive.name in ("jit", "pjit"):
+                yield from outer(e.params["jaxpr"].jaxpr)
+            else:
+                yield e
+
+    eqns = list(outer(unf.jaxpr))
     # the (B,T,X)@(X,4H) einsum appears before the scan: find a dot with a
     # T-sized operand outside any scan
-    body_eqns = [e for e in unf.jaxpr.eqns if e.primitive.name == "scan"]
+    body_eqns = [e for e in eqns if e.primitive.name == "scan"]
     assert len(body_eqns) == 1
     scan_eqn = body_eqns[0]
     inner = scan_eqn.params["jaxpr"].jaxpr
-    outer_dots = [e for e in unf.jaxpr.eqns if e.primitive.name == "dot_general"]
+    outer_dots = [e for e in eqns if e.primitive.name == "dot_general"]
     inner_dots = [e for e in inner.eqns if e.primitive.name == "dot_general"]
     assert len(outer_dots) >= 1  # hoisted W GEMM
     assert len(inner_dots) == 1  # only U·h remains serial

@@ -131,3 +131,30 @@ def test_decode_kernel_compiles(one_chip, family, dtypes):
         args.append(s((L, B, H), "float32"))
     op = lstm_decode if family == "lstm" else gru_decode
     _compile(lambda *a: op(*a, interpret=False), *args)
+
+
+def test_eesen_plan_program_compiles(one_chip):
+    """The whole EESEN plan program — every slot's hoist, pack, launch and
+    scatter as one jitted program — for bidirectional H340 L5 at B64 in
+    float32.  T60 has the slot structure of the benchmark's T300 (8-step
+    stripes, a ragged remainder, interleaved fwd/bwd waves) with fewer
+    chunks, so the compile stays short."""
+    from repro import rnn
+    from repro.dispatch import PlanProgram
+
+    B, T, X, L = 64, 60, 120, 5
+
+    def s(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def half(x):
+        return {"W": s((x, 4 * H)), "U": s((H, 4 * H)), "b": s((4 * H,))}
+
+    params = {"layers": [{"fwd": half(X if l == 0 else 2 * H),
+                          "bwd": half(X if l == 0 else 2 * H)}
+                         for l in range(L)]}
+    plan = rnn.compile(params, rnn.ExecutionPolicy(interpret=False)) \
+        .lower(B, T)
+    compiled = PlanProgram(plan, interpret=False).lower(
+        {0: params}, {0: s((B, T, X))}).compile()
+    assert "tpu_custom_call" in compiled.as_text()

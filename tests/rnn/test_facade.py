@@ -248,7 +248,7 @@ def test_plan_cache_is_bounded_lru():
     for t in range(3, 10):
         cs.lower(1, t)
     assert len(cs._plans) == 4
-    assert cs.lower(1, 9) is cs._plans[next(reversed(cs._plans))]  # hit
+    assert cs.lower(1, 9) is cs._plans[next(reversed(cs._plans))].plan  # hit
 
 
 def test_forced_reference_schedules_run_and_match():

@@ -1,9 +1,11 @@
 """Tracing threaded through compile -> forward -> prefill -> decode and
 the serving engine.  The claims: a traced run emits the expected nested
-span tree, four sibling phases per slot (hoist, pack, slot_launch,
-scatter), each a ``repro.*`` annotation in a jax.profiler capture, and
-never fences; tracing OFF leaves outputs bit-identical (and binds the
-shared no-op tracer); the fault trail is a ring buffer."""
+span tree; the call that builds a plan's program has four sibling phases
+per slot (hoist, pack, slot_launch, scatter) under its ``program`` span,
+and every call one ``program`` span; each is a ``repro.*`` annotation in
+a jax.profiler capture, and none fences; tracing OFF leaves outputs
+bit-identical (and binds the shared no-op tracer); the fault trail is a
+ring buffer."""
 import collections
 import glob
 
@@ -50,8 +52,8 @@ def test_traced_run_emits_expected_span_tree():
     assert tr.enabled and tr is not NULL_TRACER
 
     names = {s.name for s in tr.events}
-    assert {"forward", "prefill", "decode_tick", "plan", "hoist", "pack",
-            "slot_launch", "scatter", "plan_candidates"} <= names
+    assert {"forward", "prefill", "decode_tick", "plan", "program", "hoist",
+            "pack", "slot_launch", "scatter", "plan_candidates"} <= names
     # nesting: the API-level spans are roots, the per-slot work nests
     for s in tr.events:
         if s.name in ("forward", "prefill", "decode_tick"):
@@ -64,8 +66,14 @@ def test_traced_run_emits_expected_span_tree():
     for s in launches:
         assert s.tags["sig"].startswith("lstm|H48|")
         assert s.dur_us > 0.0
-    # the 3 chained decode launches share one signature
-    assert sum("|chained" in s.tags["sig"] for s in launches) == 3
+    # the 3 decode ticks share one plan: its chained launch is traced once,
+    # when the first tick builds the program, and each tick runs it
+    assert sum("|chained" in s.tags["sig"] for s in launches) == 1
+    ticks = [s for s in tr.events if s.name == "decode_tick"]
+    programs = [s for s in tr.events if s.name == "program"]
+    assert len(ticks) == 3
+    assert sum(any(t.start_us <= p.start_us <= t.start_us + t.dur_us
+                   for t in ticks) for p in programs) == 3
 
     # metrics: the decode tick histogram saw the 3 ticks' host time; the
     # fenced per-signature launch table is gone
@@ -81,37 +89,72 @@ def test_traced_run_emits_expected_span_tree():
 PHASES = ("hoist", "pack", "slot_launch", "scatter")
 
 
-def _phases_by_slot(events, root: str):
-    """{slot index: [phase names in time order]} under the last ``root``
-    span, with each phase's depth."""
-    (top,) = [s for s in events if s.name == root][-1:]
+def _under(events, root: str, index: int = -1):
+    """The spans inside the ``index``-th ``root`` span, in time order."""
+    top = [s for s in events if s.name == root][index]
     t0, t1 = top.start_us, top.start_us + top.dur_us
+    return [s for s in sorted(events, key=lambda s: s.start_us)
+            if s is not top and t0 <= s.start_us <= t1]
+
+
+def _phases_by_slot(events, root: str, index: int = -1):
+    """{slot index: [phase names in time order]} under the ``index``-th
+    ``root`` span, with each phase's depth."""
     slots = collections.defaultdict(list)
-    for s in sorted(events, key=lambda s: s.start_us):
-        if s.name in PHASES and t0 <= s.start_us <= t1:
+    for s in _under(events, root, index):
+        if s.name in PHASES:
             slots[s.tags["slot"]].append((s.name, s.depth))
     return slots
+
+
+def _programs(events, root: str, index: int = -1):
+    return [s.depth for s in _under(events, root, index)
+            if s.name == "program"]
 
 
 def test_each_packed_slot_has_four_sibling_phases():
     cs = rnn.compile(_stack(), rnn.ExecutionPolicy(interpret=True,
                                                    trace=True))
     cs.forward(_xs())
-    slots = _phases_by_slot(cs.tracer.events, "forward")
+    cs.forward(_xs())
+    slots = _phases_by_slot(cs.tracer.events, "forward", 0)
     assert sorted(slots) == [sl.index for sl in cs.plan.slots]
     for phases in slots.values():
-        # siblings, directly under forward, in execution order
-        assert phases == [(n, 1) for n in PHASES]
+        # siblings, in execution order, under the program span of the
+        # call that built the program (forward > program > phase)
+        assert phases == [(n, 2) for n in PHASES]
+    # the next call runs the built program: one program span, no phases
+    assert _phases_by_slot(cs.tracer.events, "forward", 1) == {}
+    assert _programs(cs.tracer.events, "forward", 1) == [1]
+    assert cs.stats.programs_built == 1
 
 
 def test_chained_decode_slot_has_the_same_four_phases():
     cs = rnn.compile(_stack(), rnn.ExecutionPolicy(interpret=True,
                                                    trace=True))
     ys, state = cs.prefill(_xs())
-    cs.decode(ys[:, -1:], state)
+    y, state = cs.decode(ys[:, -1:], state)
+    cs.decode(y, state)
     assert cs.last_decode_plan.slots[0].chained
-    slots = _phases_by_slot(cs.tracer.events, "decode_tick")
-    assert slots == {0: [(n, 1) for n in PHASES]}
+    slots = _phases_by_slot(cs.tracer.events, "decode_tick", 0)
+    assert slots == {0: [(n, 2) for n in PHASES]}
+    assert _phases_by_slot(cs.tracer.events, "decode_tick", 1) == {}
+    assert _programs(cs.tracer.events, "decode_tick", 1) == [1]
+
+
+@pytest.mark.parametrize("call", ["forward", "prefill", "decode_tick"])
+def test_every_call_has_one_program_span(call):
+    """Each API call dispatches its plan's program once, inside one
+    ``program`` span directly under the call's own span — the building
+    call and every call after it."""
+    cs = rnn.compile(_stack(), rnn.ExecutionPolicy(interpret=True,
+                                                   trace=True))
+    for _ in range(3):
+        _traced_session(cs)
+    roots = [s for s in cs.tracer.events if s.name == call]
+    assert len(roots) >= 3
+    for i in range(len(roots)):
+        assert _programs(cs.tracer.events, call, i) == [1]
 
 
 def _host_events(profile_dir):
@@ -132,10 +175,11 @@ def test_profiler_capture_holds_the_repro_phases(tmp_path, trace):
     cs = rnn.compile(_stack(), rnn.ExecutionPolicy(interpret=True,
                                                    trace=trace))
     xs = _xs()
-    cs.forward(xs)  # plan and compile outside the capture
     jax.profiler.start_trace(str(tmp_path))
     try:
-        jax.block_until_ready(cs.forward(xs))
+        # the first call builds the plan's program, the second runs it
+        for _ in range(2):
+            jax.block_until_ready(cs.forward(xs))
     finally:
         jax.profiler.stop_trace()
     names = _host_events(tmp_path)
@@ -143,7 +187,8 @@ def test_profiler_capture_holds_the_repro_phases(tmp_path, trace):
         assert names == {}
         return
     n_slots = len(cs.plan.slots)
-    assert names["repro.forward"] == 1
+    assert names["repro.forward"] == 2
+    assert names["repro.program"] == 2
     for phase in PHASES:
         assert names[f"repro.{phase}"] == n_slots
 
