@@ -387,8 +387,8 @@ def _fault_rows(emit, repeats: int = 3) -> None:
 
 
 def _cost_model_rows(emit, repeats: int = 3) -> None:
-    """ISSUE-9: the measured cost model, proved against the clock.  The
-    suite's canonical forward (H64 L3 T24 B1) is planned both ways after
+    """The measured cost model, proved against the clock.  A
+    four-layer forward (H32 L4 T24 B1) is planned both ways after
     an in-process calibration: ``repro.calib`` replays the launch
     signatures of BOTH competing plans — the fused per-layer slots and
     the wavefront's stripes including its G2-merged middle slots —
@@ -405,7 +405,9 @@ def _cost_model_rows(emit, repeats: int = 3) -> None:
 
     from repro.calib import Candidate, calibrate
 
-    H, L, T, B = 64, 3, 24, 1
+    # (the canonical H64 L3 stack is no contest since the planner prices
+    # weight loads: the analytic plan is already fused there)
+    H, L, T, B = 32, 4, 24, 1
     cfg = lstm_config(H, layers=L)
     stack = init_lstm_stack(jax.random.PRNGKey(0), cfg, jnp.float32)
     xs = jax.random.normal(jax.random.PRNGKey(600), (B, T, H)) * 0.5
@@ -578,22 +580,21 @@ def _verify_rows(emit, repeats: int = 3) -> None:
 
 def _quant_rows(emit, repeats: int = 3) -> None:
     """ISSUE-10: the int8 weight path, priced at a matched shape.  The
-    stripe claim first: at H512/B8/T64 the fp32 resident U (4 MB of the
-    8 MB sequence budget) caps ``select_time_block`` at bt=32, while the
-    int8 payload (1 MB + per-gate scales) sustains the full bt=64 stripe
-    — asserted >= 2x here (and in the autotune test) before anything is
-    emitted.  The timed rows run the suite's canonical stack (H64 L3 T24
-    B8, interpreter-friendly) compiled fp32 vs int8 through the SAME
-    facade; the int8 output is gated against its dequantized oracle
+    stripe first: the kernel holds U's f32 upcast in VMEM (D8), so at the
+    weight-bound H1536/B8/T64 shape the int8 payload keeps exactly fp32's
+    stripe (bt=32) — asserted here (and in the tiling test) before
+    anything is emitted.  The timed rows run the suite's canonical stack
+    (H64 L3 T24 B8, interpreter-friendly) compiled fp32 vs int8 through
+    the SAME facade; the int8 output is gated against its dequantized oracle
     (pure-jnp reference over the fake-quant param view) within the
     documented rel-err bound, and that max rel-err rides in the row."""
     from repro.core.tiling import select_time_block
     from repro.kernels.quant import fake_quant_stack
 
-    # -- the VMEM-headroom claim at the stripe-bound shape ----------------
-    bt_fp32 = select_time_block(64, 8, 512)
-    bt_int8 = select_time_block(64, 8, 512, precision="int8")
-    assert bt_int8 >= 2 * bt_fp32, (bt_int8, bt_fp32)
+    # -- the stripe at the weight-bound shape ------------------------------
+    bt_fp32 = select_time_block(64, 8, 1536)
+    bt_int8 = select_time_block(64, 8, 1536, precision="int8")
+    assert bt_int8 == bt_fp32, (bt_int8, bt_fp32)
 
     cfg, T, B = lstm_config(64, layers=3), 24, 8
     stack = init_lstm_stack(jax.random.PRNGKey(0), cfg, jnp.float32)
@@ -615,8 +616,8 @@ def _quant_rows(emit, repeats: int = 3) -> None:
     shapes = f"H{cfg.lstm_hidden}L{cfg.n_layers}T{T}B{B}"
     emit("dispatch/quant_fp32_forward",
          _time(fp.forward, xs, repeat=repeats),
-         f"{shapes} precision=fp32 stripe@H512B8T64: bt={bt_fp32}")
+         f"{shapes} precision=fp32 stripe@H1536B8T64: bt={bt_fp32}")
     emit("dispatch/quant_int8_forward",
          _time(q8.forward, xs, repeat=repeats),
-         f"{shapes} precision=int8 stripe@H512B8T64: bt={bt_int8} "
-         f"({bt_int8 // bt_fp32}x fp32) max_rel_err={rel:.1e}")
+         f"{shapes} precision=int8 stripe@H1536B8T64: bt={bt_int8} "
+         f"(fp32's: U is upcast in VMEM) max_rel_err={rel:.1e}")

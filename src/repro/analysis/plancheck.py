@@ -58,13 +58,13 @@ Tiling provenance (``stripe-align``)
     smuggle in a launch shape the offline exploration never validated.
 
 Resource budget (``vmem-budget``)
-    The per-slot VMEM footprint from tile shapes × dtype — the sequence
-    kernels' working set for packed slots, the per-layer resident set for
-    chained decode slots — fits a configurable budget (default: the
-    autotune table's own ``SEQ_VMEM_BUDGET``).  Precision-aware: an int8
-    slot is budgeted at its 1-byte resident payload plus per-gate scales
-    (bf16 at 2 bytes), and a block-sparse slot at its densest member
-    layer's occupied row-tiles plus the gather index.
+    Each slot's VMEM footprint (``Slot.footprint``: the one model of
+    ``core.tiling`` that the kernels request their scoped limit from) —
+    the sequence kernel at the slot's stripe, or one layer of the chained
+    decode kernel — fits a
+    configurable budget (default: what the kernel may use,
+    ``SEQ_VMEM_CAPACITY`` less Mosaic's headroom for a sequence slot, the
+    default scoped limit for a decode slot, which requests none).
 
 Any violation raises a structured ``runtime.errors.PlanInvariantError``
 naming the rule, slot, and cell; a clean pass returns a
@@ -79,13 +79,11 @@ from collections import Counter
 from dataclasses import dataclass
 from typing import Dict, Optional, Tuple
 
-import numpy as np
-
-from repro.core.tiling import SEQ_VMEM_BUDGET, seq_block_footprint
+from repro.core.tiling import (DEFAULT_SCOPED_VMEM, SEQ_VMEM_CAPACITY,
+                               SEQ_VMEM_HEADROOM)
 from repro.dispatch.planner import (DispatchPlan, ItemPlan, Slot,
                                     _chunk_lens, _slot_config,
                                     validate_unique_uids)
-from repro.dispatch.workitem import GATES
 from repro.runtime.errors import PlanInvariantError
 
 #: every invariant rule ``check_plan`` proves, in check order
@@ -161,41 +159,21 @@ def _item_spec(ip: ItemPlan):
     return expected, lens, dirs
 
 
-def _decode_footprint(slot: Slot) -> int:
-    """Per-layer resident VMEM of a chained decode launch: the layer's W
-    and U tiles, its bias, the chained xw row, and the (h, c) state rows
-    (fp32).  The decode kernel grid streams layers, so the budget is
-    per-layer, not the whole (L, ...) stack."""
-    gates = GATES[slot.family]
-    itemsize = np.dtype(slot.dtype).itemsize
-    weights = 2 * slot.H * gates * slot.H * itemsize + gates * slot.H * itemsize
-    rows = slot.B * gates * slot.H * itemsize + 4 * slot.B * slot.H * 4
-    return weights + rows
-
-
-def _check_slot_budget(slot: Slot, budget: int,
-                       covered: Dict[int, ItemPlan]) -> None:
-    """The footprint is precision-aware (an int8 slot's resident U is the
-    1-byte payload + per-gate scales) and sparsity-aware: a row-compacted
-    launch keeps only the densest member layer's occupied row-tiles
-    resident (slot-uniform Ha), so that density bounds the true set.
-    Unknown uids fall back dense — ``coverage-unknown`` fires right after.
-    """
-    if slot.chained:
-        used = _decode_footprint(slot)
-    else:
-        dens = max((covered[grp[0].uid].item.layer_density(grp[0].layer)
-                    for grp in slot.groups
-                    if grp and grp[0].uid in covered), default=1.0)
-        used = seq_block_footprint(slot.chunk_len, slot.B, slot.H,
-                                   gates=GATES[slot.family],
-                                   precision=slot.precision,
-                                   density=dens)
+def _check_slot_budget(slot: Slot, budget: Optional[int]) -> None:
+    """The slot's footprint at its stripe (frames per grid step, at
+    least 1) fits what its kernel may use."""
+    if slot.stripe < 1:
+        raise _fail("vmem-budget", f"stripe {slot.stripe} below one frame",
+                    slot=slot)
+    if budget is None:
+        budget = (DEFAULT_SCOPED_VMEM if slot.chained
+                  else SEQ_VMEM_CAPACITY - SEQ_VMEM_HEADROOM)
+    used = slot.footprint()
     if used > budget:
         raise _fail("vmem-budget",
                     f"footprint {used}B exceeds budget {budget}B "
                     f"({slot.family} H{slot.H} B{slot.B} "
-                    f"bt{slot.chunk_len} {slot.dtype} "
+                    f"c{slot.chunk_len} bt{slot.stripe} {slot.dtype} "
                     f"p{slot.precision})", slot=slot)
 
 
@@ -340,11 +318,9 @@ def check_plan(plan: DispatchPlan, *,
     Raises ``PlanInvariantError`` (naming rule, slot, cell) on the first
     violation; returns a ``PlanCheckReport`` on a clean pass.
 
-    ``vmem_budget`` overrides the per-slot footprint bound (default:
-    ``core.tiling.SEQ_VMEM_BUDGET``, the same working-set budget the
-    autotune table stripes against).
+    ``vmem_budget`` overrides the per-slot footprint bound (default: what
+    the slot's kernel may use — see ``vmem-budget`` above).
     """
-    budget = SEQ_VMEM_BUDGET if vmem_budget is None else vmem_budget
     validate_unique_uids([ip.item for ip in plan.items])
     covered = _covered_items(plan)
     specs = {uid: _item_spec(ip) for uid, ip in covered.items()}
@@ -353,7 +329,7 @@ def check_plan(plan: DispatchPlan, *,
     cell_wave: Dict[tuple, int] = {}
     chained = 0
     for slot in plan.slots:
-        _check_slot_budget(slot, budget, covered)
+        _check_slot_budget(slot, vmem_budget)
         _check_slot_tiling(slot, plan.macs)
         _check_slot_rows(slot, covered)
         if slot.chained:

@@ -217,6 +217,30 @@ def per_step_plan_cycles(family: str, H: int, X: int, T: int, L: int,
     return T * (per0 + (L - 1) * per) + L * T * launch_cycles
 
 
+#: HBM bandwidth and bf16 MXU peak of one TPU v5e chip, 819 GB/s and
+#: 197 TFLOP/s (Google Cloud, "TPU v5e")
+HBM_BYTES_PER_S = 819e9
+MXU_FLOPS_PER_S = 197e12
+
+
+def recurrence_compute_bound(H: int, gates: int) -> bool:
+    """Whether a recurrent step at width ``H`` is compute bound on the
+    chip: its U·h product, 2·H·gates·H FLOPs a frame, against the frame's
+    streamed float32 bytes (gates·H pre-activations in, H out), above
+    v5e's ridge of FLOP/s over HBM bytes/s.  LSTM widths from 601 up are
+    (GMAT's 1024: 410 FLOP/B); EESEN's 340 (136 FLOP/B) is not."""
+    intensity = 2 * H * gates * H / ((gates * H + H) * 4)
+    return intensity >= MXU_FLOPS_PER_S / HBM_BYTES_PER_S
+
+
+def weight_load_cycles(nbytes: float, design: Design) -> float:
+    """Cycles, at the design's clock, of moving ``nbytes`` of weights
+    through HBM: what staging each cell's recurrent matrix into VMEM
+    costs a plan on the chip, where the cycle model above has no term
+    for it."""
+    return nbytes / HBM_BYTES_PER_S * design.freq_hz
+
+
 # B rows retire through the datapath in row-tiles of this width (the MXU/
 # sublane granularity): padding a cell's B up to the tile edge is free,
 # which is what makes B-widened (padded + masked) slots usually beat an

@@ -47,7 +47,7 @@ Stack-level scheduling additionally accepts
 ``tile`` (from core.tiling) controls the dispatch granularity of the
 batch/unfolded paths, mirroring the reconfigurable tile-engine;
 ``core.tiling.select_time_block`` (via the autotune table) picks the fused
-paths' T-stripe under the VMEM budget.
+paths' T-stripe within the sequence kernels' VMEM capacity.
 
 NOTE — front-end status: the per-schedule implementations here remain the
 reference library (they ARE the paper's contribution and stay property-

@@ -576,7 +576,9 @@ def _guarded_launch(slot_index: int, uids, ladder, *, on_fault: str,
 def _seq_ladder(slot, U, xw, h0, c0, b_valid, *, u_scales=None, u_rows=None,
                 interpret):
     """The three launch strategies for a packed sequence slot, shallowest
-    first: the planned fused launch; per-step — the same kernels at
+    first: the planned fused launch (each cell's chunk walked in the
+    slot's VMEM stripes, ``slot.stripe`` frames a grid step, its U
+    resident throughout); per-step — the same kernels at
     block_t=1, one launch per timestep; and the pure-jnp reference scan.
     All three consume the identical pre-hoisted ``xw`` (bwd cells arrive
     pre-flipped), so their outputs agree to the kernel's own tolerance and
@@ -596,10 +598,10 @@ def _seq_ladder(slot, U, xw, h0, c0, b_valid, *, u_scales=None, u_rows=None,
         if lstm:
             return lstm_seq(U, xw, h0, c0, b_valid=b_valid,
                             u_scales=u_scales, u_rows=u_rows,
-                            block_t=slot.chunk_len, interpret=interpret)
+                            block_t=slot.stripe, interpret=interpret)
         out, h_n = gru_seq(U, xw, h0, b_valid=b_valid,
                            u_scales=u_scales, u_rows=u_rows,
-                           block_t=slot.chunk_len, interpret=interpret)
+                           block_t=slot.stripe, interpret=interpret)
         return out, h_n, None
 
     def per_step():

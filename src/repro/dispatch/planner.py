@@ -4,13 +4,18 @@ This is the software rendition of SHARP's intelligent tile-based dispatch
 (§5) plus dynamic reconfiguration (§6): for every admitted item the planner
 
   1. *tiles* it — the paper tile-engine K for its MVMs via
-     ``core.autotune.table().tile`` (offline table, §6.2.2), the Pallas MVM
-     block via ``table().block``, and the sequence kernel's T-stripe via
-     ``table().seq_block`` (VMEM-budgeted, per gate count);
+     ``core.autotune.table().tile`` (offline table, §6.2.2) and the Pallas
+     MVM block via ``table().block``;
   2. *schedules* it — scores candidate execution shapes (per-layer
      ``fused`` = one launch per layer, ``wavefront`` = anti-diagonal
      (layer, time-chunk) cells, ``per_step`` fallback = one launch per
-     cell) with ``core.perfmodel`` cycle estimates and picks the cheapest;
+     cell) with ``core.perfmodel`` cycle estimates plus the HBM time of
+     the recurrent-weight loads the shape makes, and picks the cheapest.
+     A cell's chunk length is the planner's choice; the kernel walks it
+     in VMEM-sized stripes (``core.tiling.select_time_block``) with U
+     resident.  Where the step is compute bound on the chip (H1024) a
+     chunk may span several stripes, so a 16 MiB U loads once per layer;
+     where it is memory bound (H340) each chunk stays one stripe;
   3. *packs* it — cells of different items that share a launch signature
      (family, H, chunk length, dtype) are co-scheduled into one global
      slot timeline, each slot one G-batched sequence-kernel launch, so
@@ -46,10 +51,14 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 from repro.core.autotune import table
 from repro.core.perfmodel import (Design, LAUNCH_CYCLES,
                                   bidir_stack_plan_cycles, decode_plan_cycles,
-                                  per_step_plan_cycles, slot_launch_cycles,
-                                  stack_plan_cycles)
+                                  per_step_plan_cycles,
+                                  recurrence_compute_bound,
+                                  slot_launch_cycles, stack_plan_cycles,
+                                  weight_load_cycles)
 from repro.core.schedules import wavefront_active
-from repro.core.tiling import SEQ_VMEM_BUDGET, seq_block_footprint
+from repro.core.tiling import (DEFAULT_SCOPED_VMEM, decode_block_footprint,
+                               select_time_block, seq_block_footprint,
+                               seq_fits, seq_vmem_limit)
 from repro.dispatch.workitem import GATES, WorkItem
 from repro.kernels.common import cdiv
 from repro.runtime.obs import NULL_TRACER, as_tracer, slot_signature
@@ -106,10 +115,41 @@ class Slot:
     #                             in this launch (part of the signature —
     #                             int8 and fp32 launches never share a
     #                             slot or a measured-cost entry)
+    stripe: int = 1             # frames per kernel grid step: each cell's
+    #                             chunk_len frames run as ceil(chunk_len /
+    #                             stripe) steps of one grid walk, U resident
 
     @property
     def g(self) -> int:
         return len(self.groups)
+
+    @property
+    def gates(self) -> int:
+        return GATES[self.family]
+
+    @property
+    def u_block_bytes(self) -> int:
+        """Bytes of one cell's recurrent matrix U (H x gates·H, as the
+        planner budgets it: 4 bytes a weight)."""
+        return self.H * self.gates * self.H * 4
+
+    def footprint(self) -> int:
+        """VMEM the launch's kernel works in (``core.tiling``): the
+        sequence kernel at this slot's stripe, or, for a chained decode
+        slot, one layer's blocks of the decode kernel."""
+        if self.chained:
+            return decode_block_footprint(self.B, self.H, gates=self.gates)
+        return seq_block_footprint(
+            self.stripe, self.B, self.H, gates=self.gates,
+            precision=self.precision,
+            masked=any(b < self.B for b in self.group_b))
+
+    def vmem_limit(self) -> int:
+        """The scoped VMEM limit the launch's kernel requests (the decode
+        kernels request none, so they get the default)."""
+        if self.chained:
+            return DEFAULT_SCOPED_VMEM
+        return seq_vmem_limit(self.footprint())
 
     @property
     def cells(self) -> Tuple[Cell, ...]:
@@ -133,7 +173,9 @@ class Slot:
             + f"]b{b}" for grp, b in zip(self.groups, self.group_b))
         tag = " chained" if self.chained else ""
         return (f"slot {self.index:3d} wave {self.wave:3d}  "
-                f"{self.family} H{self.H} B{self.B} bt{self.chunk_len} "
+                f"{self.family} H{self.H} B{self.B} c{self.chunk_len} "
+                f"bt{self.stripe} vmem {self.footprint() / 2**20:.2f}/"
+                f"{self.vmem_limit() / 2**20:.0f}MiB "
                 f"K{self.tile_k} blk{self.mvm_block}  G={self.g}{tag}  {grps}")
 
 
@@ -145,7 +187,9 @@ class ItemPlan:
     #                         decode | a forced reference schedule
     #                         (sequential/batch/intergate/unfolded — these
     #                         route external through core.schedules/core.gru)
-    block_t: int            # chosen T-stripe (0 for non-striped fallbacks)
+    block_t: int            # chosen chunk length, frames per cell (0 for
+    #                         non-striped fallbacks); each launch picks its
+    #                         own VMEM stripe (Slot.stripe)
     nk: int                 # number of time chunks
     tile_k: int
     mvm_block: Tuple[int, int]
@@ -202,6 +246,13 @@ class DispatchPlan:
     @property
     def est_cycles(self) -> float:
         return sum(ip.est_cycles for ip in self.items)
+
+    @property
+    def u_bytes_staged(self) -> int:
+        """Recurrent-weight bytes the plan's launches bring into VMEM:
+        every g of every slot stages its cell's U once (4 bytes a
+        weight).  Divided by the stack's U bytes, the loads per layer."""
+        return sum(s.g * s.u_block_bytes for s in self.slots)
 
     def describe(self) -> str:
         lines = [f"DispatchPlan: {len(self.items)} items, "
@@ -377,15 +428,14 @@ def _pack(item_plans: Sequence[ItemPlan], macs: int, *,
             gates = GATES.get(family, 1)
 
             def fits(width: int) -> bool:
-                # every item validated its block_t at its OWN B; a concat
-                # row is wider, so re-check the sequence kernels' VMEM
-                # working-set bound before widening (a singleton row always
-                # fits by the per-item validation).  The precision-narrowed
-                # weight term applies; density stays conservative at 1.0 —
-                # widening never ASSUMES sparsity
-                return seq_block_footprint(chunk_len, width, H, gates=gates,
-                                           precision=precision) \
-                    <= SEQ_VMEM_BUDGET
+                # a concat row is wider than any item alone: widen only
+                # while U and a one-frame stripe at that width still fit
+                # VMEM (the slot's stripe then shrinks to fit; density
+                # stays conservative at 1.0 — widening never ASSUMES
+                # sparsity)
+                return seq_fits(seq_block_footprint(
+                    1, width, H, gates=gates, precision=precision,
+                    masked=True))
 
             rows = []  # (lead order key, cells, valid B)
             for members in sigs[sig].values():
@@ -437,13 +487,17 @@ def _pack(item_plans: Sequence[ItemPlan], macs: int, *,
                 buckets = [rows]
             tile_k, mvm_block = _slot_config(family, H, macs)
             for bucket in buckets:
+                B = max(b for _, _, b in bucket)
                 slots.append(Slot(
                     index=len(slots), wave=s, family=family, H=H,
-                    B=max(b for _, _, b in bucket), chunk_len=chunk_len,
+                    B=B, chunk_len=chunk_len,
                     dtype=dtype, tile_k=tile_k, mvm_block=mvm_block,
                     groups=tuple(cells for _, cells, _ in bucket),
                     group_b=tuple(b for _, _, b in bucket),
-                    precision=precision))
+                    precision=precision,
+                    stripe=select_time_block(
+                        chunk_len, B, H, gates=gates, precision=precision,
+                        masked=any(b < B for _, _, b in bucket))))
     return tuple(slots)
 
 
@@ -451,17 +505,31 @@ REFERENCE_SCHEDULES = ("sequential", "batch", "intergate", "unfolded")
 FORCED_SCHEDULES = REFERENCE_SCHEDULES + ("wavefront", "fused", "per_step")
 
 
-def _fit_stripe(bt: int, B: int, H: int, gates: int,
-                precision: str = "fp32", density: float = 1.0) -> int:
-    """Halve a requested T-stripe until its sequence-kernel working set
-    fits the VMEM budget (shared by the forced and auto paths).  The
-    precision/density-narrowed weight residency applies — an int8 item
-    keeps stripes an fp32 one would have to halve."""
-    while bt > 1 and seq_block_footprint(
-            bt, B, H, gates=gates, precision=precision,
-            density=density) > SEQ_VMEM_BUDGET:
-        bt //= 2
-    return bt
+def _chunk_seed(T: int) -> int:
+    """The chunk length the candidates are built around: the longest of
+    T and the powers of two up to 256 that leaves the shortest padded
+    tail.  (It was the kernel's VMEM stripe while a cell had to fit VMEM
+    whole; the planner keeps it as the seed of its chunk candidates.)"""
+    return min((cdiv(T, bt) * bt - T, -bt, bt)
+               for bt in {min(T, p) for p in (1, 2, 4, 8, 16, 32, 64, 128,
+                                               256)})[2]
+
+
+def _one_stripe(it: WorkItem, chunk: int) -> bool:
+    """Whether the kernel walks a chunk of this length in one stripe."""
+    return select_time_block(chunk, it.B, it.H, gates=it.gates,
+                             precision=it.precision) == chunk
+
+
+def _staging_cycles(slots: Sequence[Slot], design: Design) -> float:
+    """HBM time, in the design's cycles, of the recurrent-weight loads a
+    slot timeline makes: each g of a launch stages its own U into VMEM in
+    turn (the grid walks g serially), and a slot of G > 1 cells first
+    stacks their U into one operand — read and written once more each
+    (the executor's ``_slot_weights``; a lone U is only reshaped)."""
+    return weight_load_cycles(
+        sum(s.g * s.u_block_bytes * (1 if s.g == 1 else 3) for s in slots),
+        design)
 
 
 def _stack_est(it: WorkItem, design: Design, *, nk: int) -> float:
@@ -487,9 +555,13 @@ def _per_step_plan(it: WorkItem, design: Design, tile_k, mvm_block,
                    dirs: int = 1) -> ItemPlan:
     """lstm per_step runs one cell-kernel launch per (layer, step); gru has
     no per-step pallas kernel (pure-jnp scan -> zero launches)."""
-    est = dirs * sum(per_step_plan_cycles(f, it.H, it.X, it.T, n, design)
-                     for f, n in sorted(Counter(it.families).items()))
     n_lstm = sum(1 for f in it.families if f == "lstm")
+    # every per-step launch stages its layer's U (H x 4H, 4 bytes a
+    # weight) again
+    u_bytes = it.H * 4 * it.H * 4
+    est = dirs * (sum(per_step_plan_cycles(f, it.H, it.X, it.T, n, design)
+                      for f, n in sorted(Counter(it.families).items()))
+                  + weight_load_cycles(n_lstm * it.T * u_bytes, design))
     return ItemPlan(item=it, schedule="per_step", block_t=0, nk=it.T,
                     tile_k=tile_k, mvm_block=mvm_block,
                     naive_launches=dirs * n_lstm * it.T, est_cycles=est)
@@ -535,17 +607,13 @@ def _forced_plan(it: WorkItem, design: Design, force: str, force_bt: int,
                             nk=1, tile_k=tile_k, mvm_block=mvm_block,
                             naive_launches=it.L, est_cycles=est)
         # bidirectional "fused" is the one-wave-per-layer shape of the
-        # interleaved timeline (nk collapses to 1 when the whole T fits the
-        # VMEM budget — one G=2 launch per layer, fwd and bwd merged —
-        # otherwise the minimal striping that does fit)
+        # interleaved timeline: one G=2 launch per layer, fwd and bwd
+        # merged
         force_bt = force_bt or it.T
-    # wavefront: forced stripe if given (VMEM-checked), else the autotuned
-    # one — nk may collapse to 1, which IS the packable fused shape
-    bt = _fit_stripe(min(it.T, force_bt) if force_bt else
-                     table().seq_block(it.T, it.B, it.H, gates=it.gates,
-                                       precision=it.precision,
-                                       density=it.max_density),
-                     it.B, it.H, it.gates, it.precision, it.max_density)
+    # wavefront: the forced chunk length if given, else the seed — nk may
+    # collapse to 1, which IS the packable fused shape; the kernel stripes
+    # each chunk to fit VMEM, so any chunk length runs
+    bt = min(it.T, force_bt) if force_bt else _chunk_seed(it.T)
     nk = cdiv(it.T, bt)
     est = _wave_est(it, design, nk=nk)
     ip = ItemPlan(item=it, schedule="wavefront" if nk > 1 else "fused",
@@ -610,29 +678,34 @@ def _schedule_item(it: WorkItem, macs: int, design: Design,
         return _forced_plan(it, design, force, force_bt, tile_k, mvm_block)
 
     if force_bt:
-        # an explicit stripe override (ExecutionPolicy.block_t) pins the
+        # an explicit chunk override (ExecutionPolicy.block_t) pins the
         # wavefront candidate even under "auto" — the scorer still weighs
-        # it against per_step, but never re-stripes it
-        cands = [_fit_stripe(min(it.T, force_bt), it.B, it.H, it.gates,
-                             it.precision, it.max_density)]
+        # it against per_step, but never re-chunks it
+        cands = [min(it.T, force_bt)]
     else:
-        bt0 = table().seq_block(it.T, it.B, it.H, gates=it.gates,
-                                precision=it.precision,
-                                density=it.max_density)
-        cands = sorted({min(it.T, bt0), min(it.T, max(1, bt0 // 2)),
-                        min(it.T, bt0 * 2), it.T})
-        # wider-than-bt0 candidates must still respect the sequence
-        # kernels' VMEM working-set bound the autotune table enforces
-        cands = [bt for bt in cands
-                 if bt <= 1 or seq_block_footprint(
-                     bt, it.B, it.H, gates=it.gates,
-                     precision=it.precision, density=it.max_density)
-                 <= SEQ_VMEM_BUDGET] or [min(it.T, bt0)]
+        bt0 = _chunk_seed(it.T)
+        cands = sorted({bt0, max(1, bt0 // 2), min(it.T, bt0 * 2), it.T})
+        if not recurrence_compute_bound(it.H, it.gates):
+            # a memory-bound step: a chunk longer than one VMEM stripe
+            # streams its gate pre-activations around the kernel through
+            # HBM, which cost EESEN's whole-layer chunks 44% on the chip
+            # (PERF.md) — keep each cell to one stripe, as the kernel
+            # holds it whole
+            cands = [bt for bt in cands if _one_stripe(it, bt)] or [
+                select_time_block(it.T, it.B, it.H, gates=it.gates,
+                                  precision=it.precision)]
     scored = []
     for bt in cands:
         nk = cdiv(it.T, bt)
-        est = _wave_est(it, design, nk=nk)
-        scored.append((est, -bt, bt, nk, "wavefront" if nk > 1 else "fused"))
+        sched = "wavefront" if nk > 1 else "fused"
+        # compute and launches (perfmodel) plus the weight loads of the
+        # item's own slot timeline
+        trial = ItemPlan(item=it, schedule=sched, block_t=bt, nk=nk,
+                         tile_k=tile_k, mvm_block=mvm_block,
+                         naive_launches=0, est_cycles=0.0)
+        est = (_wave_est(it, design, nk=nk)
+               + _staging_cycles(_pack([trial], macs), design))
+        scored.append((est, -bt, bt, nk, sched))
     ps = _per_step_plan(it, design, tile_k, mvm_block, dirs=it.dirs)
     scored.append((ps.est_cycles, 0, 0, it.T, "per_step"))
 
@@ -726,7 +799,8 @@ def plan(items: Iterable[WorkItem], *, macs: int = DEFAULT_MACS,
     None/0 = score freely.
 
     ``tracer``: an optional ``runtime.obs.Tracer`` — planning gets a
-    ``plan`` span tagged with the outcome (slots/launches/est_cycles) and
+    ``plan`` span tagged with the outcome (slots/launches/est_cycles, and
+    per slot its chunk, stripe, VMEM footprint and limit) and
     each auto-scored item emits a ``plan_candidates`` instant with its
     chosen-vs-rejected schedule scores.
 
@@ -773,8 +847,18 @@ def plan(items: Iterable[WorkItem], *, macs: int = DEFAULT_MACS,
         out = DispatchPlan(items=tuple(plans[it.uid] for it in items),
                            slots=slots, external=tuple(external), macs=macs)
         sp.tag(slots=len(out.slots), launches=out.launches,
-               est_cycles=out.est_cycles)
+               est_cycles=out.est_cycles, **slot_tags(out.slots))
     return out
+
+
+def slot_tags(slots: Sequence[Slot]) -> Dict[str, tuple]:
+    """Per-slot launch shape, slot by slot, as a ``plan`` span records it:
+    the chunk (frames per cell), the stripe (frames per grid step), the
+    VMEM footprint and the scoped VMEM limit the kernel requests."""
+    return {"chunks": tuple(s.chunk_len for s in slots),
+            "stripes": tuple(s.stripe for s in slots),
+            "footprints": tuple(s.footprint() for s in slots),
+            "vmem_limits": tuple(s.vmem_limit() for s in slots)}
 
 
 def plan_decode(items: Iterable[WorkItem], *, macs: int = DEFAULT_MACS,
@@ -856,6 +940,18 @@ def plan_decode(items: Iterable[WorkItem], *, macs: int = DEFAULT_MACS,
             f"({head.family} H{head.H} L{head.L}) — the perfmodel broke",
             rule="decode-cost-model", uids=[it.uid for it in items])
     B_total = sum(it.B for it in items)
+    need = decode_block_footprint(B_total, head.H, gates=head.gates)
+    if need > DEFAULT_SCOPED_VMEM:
+        # the chained kernel streams a whole layer's W and U per grid step
+        # and requests no VMEM limit of its own
+        from repro.runtime.errors import PlanRejected
+        raise PlanRejected(
+            f"no chained decode kernel fits: {head.family} H{head.H} "
+            f"L{head.L} B{B_total} {head.dtype} needs "
+            f"{need / 2**20:.1f} MiB of VMEM a layer, above the "
+            f"{DEFAULT_SCOPED_VMEM / 2**20:.0f} MiB the decode kernels get; "
+            "run whole sequences through forward()/prefill()",
+            uids=[it.uid for it in items])
 
     # measured mode: chained-vs-loop is a real decision, scored in µs.
     # The per-layer alternative is the generic planner's own plan (the
@@ -891,7 +987,7 @@ def plan_decode(items: Iterable[WorkItem], *, macs: int = DEFAULT_MACS,
         return alt
 
     with tracer.span("plan", n_items=len(items), schedule="decode",
-                     est_cycles=est_chain):
+                     est_cycles=est_chain) as sp:
         item_plans = tuple(
             ItemPlan(item=it, schedule="decode", block_t=1, nk=1,
                      tile_k=tile_k, mvm_block=mvm_block,
@@ -906,6 +1002,7 @@ def plan_decode(items: Iterable[WorkItem], *, macs: int = DEFAULT_MACS,
                                  for l in range(head.L)),
                     group_b=(B_total,) * head.L, chained=True,
                     precision=head.precision)
+        sp.tag(**slot_tags((slot,)))
     return DispatchPlan(items=item_plans, slots=(slot,), external=(),
                         macs=macs)
 
@@ -941,14 +1038,6 @@ def _align_group_stripes(items: Sequence[WorkItem],
         out = []
         for m in members:
             mbt = min(bt, m.T) if bt else plans[m.uid].block_t
-            # a cross-B group mixes batch widths: the shared stripe must
-            # respect the VMEM working-set bound at each member's OWN B
-            # (its original block_t was only validated there) — members the
-            # stripe doesn't fit keep their own validated choice
-            if mbt > 1 and seq_block_footprint(
-                    mbt, m.B, m.H, gates=m.gates, precision=m.precision,
-                    density=m.max_density) > SEQ_VMEM_BUDGET:
-                mbt = plans[m.uid].block_t
             nk = cdiv(m.T, mbt)
             est = stack_plan_cycles(m.family, m.H, m.X, m.T, m.L, design,
                                     nk=nk)

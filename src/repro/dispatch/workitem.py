@@ -33,8 +33,9 @@ GATES = {"lstm": 4, "gru": 3, "rglru": 1}
 #: Weight precisions the fused sequence kernels execute: "fp32" is the
 #: bit-exact default; "bf16" round-trips the recurrent matrix through
 #: bfloat16 (exact vs its dequantized oracle); "int8" stores U as a
-#: per-gate absmax int8 payload (4x smaller VMEM residency, fp32
-#: accumulate) — bounded-error vs the dequantized oracle, not bit-equal
+#: per-gate absmax int8 payload (4x fewer HBM bytes; the kernel upcasts
+#: it to f32 in VMEM; fp32 accumulate) — bounded-error vs the dequantized
+#: oracle, not bit-equal
 #: (see kernels.quant).
 PRECISIONS = ("fp32", "bf16", "int8")
 

@@ -17,6 +17,22 @@ def round_up(a: int, b: int) -> int:
     return cdiv(a, b) * b
 
 
+def seq_vmem_limit_of(U, xw, h0, bt: int, *, quant: bool, sparse: bool,
+                      masked: bool) -> int:
+    """A sequence-kernel launch's scoped VMEM limit, from the one
+    footprint model the planner and plancheck budget with
+    (``core.tiling``), at the launch's own shapes and dtypes: U
+    (G, Hr, gates·H), xw (G, B, T, gates, H), h0 (G, B, H)."""
+    from repro.core.tiling import seq_block_footprint, seq_vmem_limit
+
+    _, B, _, gates, H = xw.shape
+    return seq_vmem_limit(seq_block_footprint(
+        bt, B, H, gates=gates,
+        act_bytes=max(xw.dtype.itemsize, h0.dtype.itemsize),
+        weight_bytes=U.dtype.itemsize, precision="int8" if quant else "fp32",
+        density=U.shape[1] / H if sparse else 1.0, masked=masked))
+
+
 def ragged_b_mask(G: int, B: int, b_valid):
     """(G, B) int32 validity mask from per-cell valid row counts (ragged-B
     packing): mask[g, b] = 1 iff b < b_valid[g].  Shared by the sequence

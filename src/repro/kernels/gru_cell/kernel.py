@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cdiv
+from repro.kernels.common import cdiv, seq_vmem_limit_of
 
 
 def _seq_kernel(*refs, block_t: int, T: int, masked: bool,
@@ -160,6 +160,9 @@ def gru_seq_pallas(U3, xw, h0, *, block_t: int, interpret: bool = True,
             pltpu.VMEM((B, H), jnp.float32),       # h — resident across t
             pltpu.VMEM((bt, B, H), jnp.float32),   # this block's h stripe
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=seq_vmem_limit_of(
+                U3, xw, h0, bt, quant=quant, sparse=sparse, masked=masked)),
         interpret=interpret,
     )(*args)
     return jnp.swapaxes(hs, 1, 2), h_n

@@ -21,7 +21,7 @@ def gru_seq(U3, xw, h0=None, *, b_valid=None, u_scales=None, u_rows=None,
     (B,T,3,H) / (G,B,T,3,H) precomputed input half; h0 optional (…B,H)
     initial state (zeros when omitted).  Returns (hs, h_T); ``hs`` is
     (…B,T,H).  ``block_t`` (the streamed T-stripe) defaults to the autotune
-    table's VMEM-budget choice (gates=3).
+    table's choice within the VMEM capacity (gates=3).
 
     ``b_valid`` (stacked form only): (G,) int array of valid batch rows per
     cell under ragged-B packing — rows >= b_valid[g] are exact no-ops.

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cdiv
+from repro.kernels.common import cdiv, seq_vmem_limit_of
 
 
 def _kernel(h_ref, u_ref, xw_ref, c_ref, h_out_ref, c_out_ref, acc_ref, *,
@@ -130,10 +130,11 @@ def _seq_kernel(*refs, block_t: int, T: int, masked: bool,
     mask, so they are exact no-ops and h_T/c_T of valid rows are bit-exact.
 
     ``quant``: U arrives int8 with a per-lane (1, 4H) scale operand (each
-    gate's scale repeated over its H lanes); the int8 payload is what sits
-    resident in VMEM (4x smaller), the dot accumulates in fp32 over the
-    scale-free upcast, and the scale is applied to the (B, 4H) accumulate
-    after the dot — so the only error vs the dequantized oracle is the
+    gate's scale repeated over its H lanes); the int8 payload streams in
+    with 4x fewer HBM bytes, but the dot reads its f32 upcast, so it holds
+    fp32's VMEM (core.tiling.seq_block_footprint, D8).  The dot
+    accumulates in fp32 and the scale is applied to the (B, 4H) accumulate
+    after it, so the only error vs the dequantized oracle is the
     distributivity of ``(h @ Uq) * s``.
 
     ``sparse``: U arrives row-compacted (Ha <= H input rows) with a
@@ -277,6 +278,9 @@ def lstm_seq_pallas(U4, xw, h0, c0, *, block_t: int, interpret: bool = True,
             pltpu.VMEM((B, H), jnp.float32),       # c — resident across t
             pltpu.VMEM((bt, B, H), jnp.float32),   # this block's h stripe
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=seq_vmem_limit_of(
+                U4, xw, h0, bt, quant=quant, sparse=sparse, masked=masked)),
         interpret=interpret,
     )(*args)
     return jnp.swapaxes(hs, 1, 2), h_n, c_n

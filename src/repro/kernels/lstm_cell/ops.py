@@ -53,7 +53,8 @@ def lstm_seq(U4, xw, h0=None, c0=None, *, b_valid=None, u_scales=None,
     (B,T,4,H) / (G,B,T,4,H) precomputed input half; h0/c0 optional (…B,H)
     initial state (each defaults to zeros when omitted, independently).
     Returns (hs, h_T, c_T); ``hs`` is (…B,T,H).  ``block_t`` (the streamed
-    T-stripe) defaults to the autotune table's VMEM-budget choice.
+    T-stripe) defaults to the autotune table's choice within the VMEM
+    capacity (``core.tiling.select_time_block``).
 
     ``b_valid`` (stacked form only): (G,) int array of valid batch rows per
     cell when ragged-B cells were padded to a common B — rows >= b_valid[g]

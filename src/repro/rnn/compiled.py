@@ -75,6 +75,12 @@ class StackStats:
     the signal).  All of these stay zero/empty on a healthy stack — they
     are the degradation signal the serving layer watches.
 
+    ``u_bytes_staged`` counts the recurrent-weight bytes the calls'
+    launches brought into VMEM (``DispatchPlan.u_bytes_staged``: every g
+    of every slot stages its cell's U, 4 bytes a weight), so a window's
+    delta over its calls and over the stack's U bytes is how many times
+    each layer's U was loaded per call.
+
     ``measured_hits``/``analytic_fallbacks`` (policy
     ``cost_model="measured"``) count the measured cost model's lookup
     resolutions across every plan this stack built: hits include
@@ -93,6 +99,7 @@ class StackStats:
     plans_built: int = 0
     plans_verified: int = 0
     programs_built: int = 0
+    u_bytes_staged: int = 0
     decode_launches: int = 0
     decode_plans_built: int = 0
     degraded_launches: int = 0
@@ -376,6 +383,7 @@ class CompiledStack:
                  report: Optional[ExecutionReport] = None) -> None:
         self.stats.launches += p.launches
         self.stats.est_cycles += p.est_cycles
+        self.stats.u_bytes_staged += p.u_bytes_staged
         if report is not None and report.faults:
             self.stats.degraded_launches += report.degraded_launches
             self.stats.fallback_level = max(self.stats.fallback_level,
@@ -565,6 +573,7 @@ class CompiledStack:
             f"{s.plans_built} plans built ({s.decode_plans_built} decode, "
             f"{s.plans_verified} verified), "
             f"{s.programs_built} programs built, "
+            f"{s.u_bytes_staged / 2**20:.1f} MiB of U staged, "
             f"est {s.est_cycles:.0f}cy",
             f"  plan cache: {len(self._plans)} shapes",
         ]

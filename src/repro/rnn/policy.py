@@ -70,9 +70,10 @@ class ExecutionPolicy:
 
     schedule:  "auto" (planner-scored) or a forced schedule — one of
                ``SCHEDULES``.
-    block_t:   wavefront T-stripe override, honored under "auto" too (the
-               scorer then only weighs the pinned stripe against
-               per_step); 0 = autotuned (VMEM-budgeted).
+    block_t:   wavefront chunk-length override, frames per cell, honored
+               under "auto" too (the scorer then only weighs the pinned
+               chunk against per_step); 0 = scored.  Each launch walks
+               its chunk in the VMEM stripe core.tiling picks.
     interpret: force Pallas interpret mode (None = auto: interpret
                everywhere but real TPUs).
     dtype:     cast inputs before execution; None = keep the caller's.

@@ -4,10 +4,12 @@ import math
 import pytest
 from tests._hyp import given, settings, st
 
-from repro.core.tiling import (K_CHOICES, TileConfig, block_waste, mvm_cycles,
+from repro.core.tiling import (K_CHOICES, MiB, SEQ_VMEM_CAPACITY,
+                               TileConfig, block_waste, cdiv, mvm_cycles,
                                padding_waste, select_block_shape,
                                select_time_block, select_tile,
-                               seq_block_footprint)
+                               seq_block_footprint, seq_fits, seq_vmem_limit)
+from repro.runtime.errors import PlanRejected
 
 
 @settings(max_examples=50, deadline=None)
@@ -78,8 +80,12 @@ def test_select_time_block():
     bt = select_time_block(64, 4, 256)
     assert 1 <= bt <= 64 and 64 % bt == 0   # zero T-edge waste is available
     assert select_time_block(7, 2, 96) == 7  # exact fit beats padded stripes
-    # huge H: U alone blows the budget -> degenerate single-step stripe
-    assert select_time_block(64, 8, 2048) == 1
+    # huge H: two buffers of the 36 MiB U leave room for a 41-frame
+    # stripe, so T=64 runs in two even steps with U resident
+    assert select_time_block(64, 8, 1536) == 32
+    # U alone above the capacity: refused, never a silent bt=1 fallback
+    with pytest.raises(PlanRejected, match="H2048"):
+        select_time_block(64, 8, 2048)
 
 
 @settings(max_examples=30, deadline=None)
@@ -88,25 +94,28 @@ def test_select_time_block():
 def test_time_block_constraints(T, B, H):
     bt = select_time_block(T, B, H)
     assert 1 <= bt <= T
-    if bt > 1:  # within the fused kernel's VMEM budget
-        assert 4 * (4 * H * H + B * bt * 5 * H + 4 * B * H) <= 8 * 2**20
+    assert seq_fits(seq_block_footprint(bt, B, H))   # within the capacity
+    steps = cdiv(T, bt)
+    assert steps * bt - T < steps                    # split evenly
+    if steps > 1:  # the fewest grid steps: one fewer would not fit
+        assert not seq_fits(seq_block_footprint(cdiv(T, steps - 1), B, H))
 
 
 def test_time_block_int8_doubles_stripe_when_weight_bound():
-    """ISSUE-10 acceptance: at the stripe-bound H512/B8/T64 shape the fp32
-    resident U is 4 MB of the 8 MB budget and caps bt at 32; the int8
-    payload (1 MB + per-gate scales) frees enough VMEM to keep the full
-    T=64 stripe — a >= 2x larger time block from precision alone."""
-    bt_fp32 = select_time_block(64, 8, 512)
-    bt_int8 = select_time_block(64, 8, 512, precision="int8")
-    assert bt_fp32 == 32 and bt_int8 == 64
-    assert bt_int8 >= 2 * bt_fp32
-    # bf16 sits between: half the weight bytes also unlocks the full stripe
-    assert select_time_block(64, 8, 512, precision="bf16") == 64
-    # footprint math agrees with the selection at the boundary
-    assert seq_block_footprint(64, 8, 512) > 8 * 2**20           # fp32: no
-    assert seq_block_footprint(64, 8, 512,
-                               precision="int8") <= 8 * 2**20    # int8: yes
+    """The int8 payload no longer buys a longer stripe: the kernel holds
+    U's f32 upcast in VMEM (D8), and AOT compiles for v5e need the same
+    VMEM for an int8 and an fp32 U at H1024 B64 (40.5 MiB at bt=8).  So
+    at the weight-bound H1536/B8/T64 shape int8 keeps fp32's stripe, as
+    does precision="bf16" (its U stays in float32 storage); only U stored
+    in bfloat16 halves the weight term and keeps the whole T."""
+    bt_fp32 = select_time_block(64, 8, 1536)
+    assert bt_fp32 == 32
+    assert select_time_block(64, 8, 1536, precision="int8") == bt_fp32
+    assert select_time_block(64, 8, 1536, precision="bf16") == bt_fp32
+    assert select_time_block(64, 8, 1536, weight_bytes=2) == 64
+    # the int8 footprint is fp32's plus the scale row (two f32 buffers)
+    assert seq_block_footprint(32, 8, 1536, precision="int8") == \
+        seq_block_footprint(32, 8, 1536) + 2 * 8 * 4 * 1536 * 4
 
 
 def test_time_block_density_discount():
@@ -115,10 +124,40 @@ def test_time_block_density_discount():
     same weight-bound shape; density=1.0 is byte-identical to dense."""
     assert seq_block_footprint(32, 8, 512, density=1.0) == \
         seq_block_footprint(32, 8, 512)
-    dense = select_time_block(64, 8, 512)
-    sparse = select_time_block(64, 8, 512, density=0.25)
+    dense = select_time_block(64, 8, 1536)
+    sparse = select_time_block(64, 8, 1536, density=0.25)
     assert sparse >= 2 * dense
     half = seq_block_footprint(32, 8, 512, density=0.5)
     full = seq_block_footprint(32, 8, 512)
-    w = 4 * 4 * 512 * 512
-    assert half == full - w + int(w * 0.5) + 4 * 256  # rows operand added
+    w = 2 * 4 * 4 * 512 * 512                 # two buffers of U
+    # half the rows, plus the (1, 256) int32 row index, two (8, 256) tiles
+    assert half == full - w + w // 2 + 2 * 8 * 256 * 4
+
+
+def test_footprint_matches_the_v5e_compiler_and_counts_padding():
+    """AOT compiles of lstm_seq for a described v5e at H1024 B64 float32
+    need 2.75 MiB of scoped VMEM a frame of stripe, over 18.5 MiB (one
+    16 MiB U) for the kernel compiled alone — 40.5 MiB at an 8-frame
+    stripe, 62.5 at 16 — and over two U less 0.7 MiB inside GMAT's plan
+    program (86.04 MiB at its 19-frame stripe).  The model counts two
+    buffers of U, so it reads 16 MiB above the first and 0.7 MiB above
+    the second.  B pads to 8 sublanes and 4H=1360 to 1408 lanes, so
+    B7/H340 costs what B8 does and more than its unpadded bytes."""
+    U = 16 * MiB
+    assert seq_block_footprint(8, 64, 1024) == 40.5 * MiB + U
+    assert seq_block_footprint(16, 64, 1024) == 62.5 * MiB + U
+    assert 0 <= seq_block_footprint(19, 64, 1024) - 86.04 * MiB < MiB
+    assert seq_block_footprint(8, 7, 340) == seq_block_footprint(8, 8, 340)
+    unpadded = 4 * (340 * 1360 + 2 * 8 * 7 * (1360 + 340) + 8 * 7 * 340)
+    assert seq_block_footprint(8, 7, 340) > unpadded
+    assert seq_block_footprint(8, 8, 340, gates=3) < \
+        seq_block_footprint(8, 8, 340)
+
+
+def test_vmem_limit_is_the_default_below_it_and_capped_above():
+    """A kernel that fits the default scoped limit asks for exactly it (so
+    it compiles as before); a larger one asks for its footprint plus
+    headroom, never above the capacity."""
+    assert seq_vmem_limit(seq_block_footprint(8, 64, 340)) == 16 * MiB
+    assert seq_vmem_limit(seq_block_footprint(16, 64, 1024)) == 81 * MiB
+    assert seq_vmem_limit(10 * SEQ_VMEM_CAPACITY) == SEQ_VMEM_CAPACITY

@@ -163,14 +163,18 @@ def test_from_config_requires_a_recurrence():
 
 
 def test_stripe_candidates_respect_vmem_budget():
-    """T-wide stripes the autotune table would reject must not sneak in
-    through the planner's candidate widening."""
-    from repro.core.tiling import SEQ_VMEM_BUDGET, seq_block_footprint
+    """T-wide chunks VMEM cannot hold whole must not sneak in through the
+    planner's candidate widening at a memory-bound width (H512): each
+    cell stays one stripe that fits the capacity, where the whole T=512
+    would not."""
+    from repro.core.tiling import seq_block_footprint, seq_fits
 
-    it = WorkItem(uid=0, family="lstm", B=2, T=512, H=512, L=1)
-    ip = plan([it]).item(0)
-    assert seq_block_footprint(ip.block_t, it.B, it.H,
-                               gates=it.gates) <= SEQ_VMEM_BUDGET
+    it = WorkItem(uid=0, family="lstm", B=16, T=512, H=512, L=1)
+    p = plan([it])
+    assert not seq_fits(seq_block_footprint(it.T, it.B, it.H))
+    assert seq_fits(seq_block_footprint(p.item(0).block_t, it.B, it.H))
+    for s in p.slots:
+        assert s.stripe == s.chunk_len and seq_fits(s.footprint())
 
 
 def test_plan_only_items_are_flagged():
@@ -217,18 +221,21 @@ def test_share_groups_require_matching_layers_only():
 
 
 def test_cross_b_concat_respects_vmem_budget():
-    """Concat rows are wider than any width the per-item block_t was
-    validated at — the packer must split a share group rather than emit a
-    row whose working set blows the sequence kernels' VMEM bound."""
-    from repro.core.tiling import SEQ_VMEM_BUDGET, seq_block_footprint
+    """Concat rows are wider than any item alone — the packer must split a
+    share group rather than emit a row at which U and even a one-frame
+    stripe blow the sequence kernels' VMEM capacity (H1536: two buffers
+    of a 36 MiB U)."""
+    from repro.core.tiling import seq_block_footprint, seq_fits
 
-    items = [WorkItem(uid=i, family="lstm", B=4, T=256, H=512, share=0,
+    items = [WorkItem(uid=i, family="lstm", B=64, T=8, H=1536, share=0,
                       L=1) for i in range(6)]
     p = plan(items)
+    assert not seq_fits(seq_block_footprint(1, 6 * 64, 1536))
+    assert max(len(grp) for s in p.slots for grp in s.groups) < 6
     for s in p.slots:
+        assert seq_fits(s.footprint())
         for b in s.group_b:
-            assert seq_block_footprint(s.chunk_len, b, s.H,
-                                       gates=4) <= SEQ_VMEM_BUDGET
+            assert seq_fits(seq_block_footprint(1, b, s.H))
     # ...while small shapes still concat into single rows
     small = plan([WorkItem(uid=i, family="lstm", B=1, T=8, H=32, L=2,
                            share=0) for i in range(3)])
@@ -236,19 +243,17 @@ def test_cross_b_concat_respects_vmem_budget():
 
 
 def test_stripe_alignment_respects_each_members_vmem_budget():
-    """Regression: cross-B stripe alignment must not hand a large-B item a
-    stripe that was only budget-valid at a small-B partner's width — every
-    plan's (block_t, B) working set stays within the kernels' bound."""
-    from repro.core.tiling import SEQ_VMEM_BUDGET, seq_block_footprint
+    """Regression: cross-B chunk alignment must not hand a large-B item a
+    stripe that was only valid at a small-B partner's width — each launch
+    stripes at its own (widest) row, so every slot's working set stays
+    within the kernels' capacity."""
+    from repro.core.tiling import seq_fits
 
     items = [WorkItem(uid=0, family="lstm", B=1, T=512, H=512, L=2),
              WorkItem(uid=1, family="lstm", B=32, T=512, H=512, L=2)]
     p = plan(items)
-    for ip in p.items:
-        if ip.block_t > 1:
-            assert seq_block_footprint(ip.block_t, ip.item.B, ip.item.H,
-                                       gates=ip.item.gates) \
-                <= SEQ_VMEM_BUDGET, ip.describe()
+    for s in p.slots:
+        assert seq_fits(s.footprint()), s.describe()
 
 
 def test_decode_plan_is_one_chained_slot():

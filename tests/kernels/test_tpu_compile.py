@@ -18,7 +18,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.tiling import select_time_block
+from repro.core.tiling import (cdiv, select_time_block, seq_block_footprint,
+                               seq_fits)
 from repro.kernels.gru_cell.ops import gru_decode, gru_seq
 from repro.kernels.lstm_cell.ops import lstm_decode, lstm_seq
 
@@ -97,13 +98,17 @@ def test_int8_seq_kernel_compiles(one_chip, family):
 @pytest.mark.parametrize("family", ["lstm", "gru"])
 def test_seq_kernel_compiles_at_vmem_budget_edge(one_chip, family, wdt,
                                                  precision):
-    """The widest stripe the planner's footprint budget admits at H340:
-    B=7 pads to 8 sublanes and the gate lanes 4H=1360 to 1408, which the
-    footprint model does not count, so this is where scoped VMEM would
-    overflow first."""
-    G, B, T = 2, 7, 256
-    bt = select_time_block(T, B, H, gates=GATES[family], precision=precision)
-    assert bt == 128
+    """The widest stripe the planner's VMEM capacity admits at H340, where
+    the kernel asks for nearly all of it as its scoped limit: B=7 pads to
+    8 sublanes and the gate lanes 4H=1360 to 1408, and a T longer than
+    any stripe makes the walk take the capacity's stripe (one fewer grid
+    step would not fit)."""
+    G, B, T = 2, 7, 4096
+    g = GATES[family]
+    bt = select_time_block(T, B, H, gates=g, precision=precision)
+    steps = cdiv(T, bt)
+    assert steps > 1 and not seq_fits(seq_block_footprint(
+        cdiv(T, steps - 1), B, H, gates=g, precision=precision))
     args, kw = _seq_args(one_chip, family, G, B, T, wdt, "float32")
     _compile(_seq_fn(family, bt), *args, **kw)
 
@@ -158,3 +163,47 @@ def test_eesen_plan_program_compiles(one_chip):
     compiled = PlanProgram(plan, interpret=False).lower(
         {0: params}, {0: s((B, T, X))}).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("G", [1, 2])
+@pytest.mark.parametrize("family", ["lstm", "gru"])
+def test_h1024_seq_kernel_compiles(one_chip, family, G):
+    """GMAT's width: one float32 U is 16 MiB, the default scoped VMEM of a
+    v5e kernel, so the kernel must ask for the limit its footprint needs.
+    B64 with the stripe the planner picks for GMAT's 75-frame chunks
+    (19: three full grid steps and a ragged fourth), at G=1 (GMAT's plan)
+    and G=2 (the next cell's U fetched at the g boundary)."""
+    Hw, B, T, g = 1024, 64, 75, GATES[family]
+    bt = select_time_block(T, B, Hw, gates=g)
+    if family == "lstm":
+        assert bt == 19
+
+    def s(shape, dt="float32"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    args = [s((G, Hw, g, Hw)), s((G, B, T, g, Hw)), s((G, B, Hw))]
+    if family == "lstm":
+        args.append(s((G, B, Hw)))
+    _compile(_seq_fn(family, bt), *args)
+
+
+def test_gmat_plan_program_compiles(one_chip):
+    """The whole GMAT plan program — 17 unidirectional H1024 layers at B64
+    in float32, every hoisted input GEMM and lstm_seq launch — at T16, a
+    chunk of 16 frames per layer, so the compile stays short."""
+    from repro import rnn
+    from repro.dispatch import PlanProgram
+
+    B, T, Hw, L = 64, 16, 1024, 17
+
+    def s(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    params = {"layers": [{"W": s((Hw, 4 * Hw)), "U": s((Hw, 4 * Hw)),
+                          "b": s((4 * Hw,))} for _ in range(L)]}
+    plan = rnn.compile(params, rnn.ExecutionPolicy(interpret=False)) \
+        .lower(B, T)
+    assert plan.launches == L
+    compiled = PlanProgram(plan, interpret=False).lower(
+        {0: params}, {0: s((B, T, Hw))}).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= L
