@@ -460,12 +460,15 @@ def _slot_weights(slot, params, live, memo: dict):
     """Stack one sequence slot's per-group recurrent-weight operands under
     the slot's precision and its items' block-sparsity tile maps.
 
-    Returns ``(U, u_scales, u_rows)``: dense fp32 ``(G, H, gates, H)`` with
-    None markers for a plain fp32 slot; bf16 round-trips the values in
-    place (still f32 storage — exact); int8 swaps in the per-gate quantized
-    payload plus ``u_scales (G, gates)``; a tile_map row-compacts to the
+    Returns ``(U, u_scales, u_rows)``: dense ``(G, H, gates, H)`` in the
+    dtype the parameters hold U in (fp32, or bfloat16 where
+    ``stage_weights`` staged it) with None markers for a plain slot; bf16
+    round-trips fp32 values in place (still f32 storage — exact; a staged
+    U already holds them); int8 swaps in the per-gate quantized payload
+    plus ``u_scales (G, gates)``; a tile_map row-compacts to the
     slot-uniform ``Ha`` active-row count plus ``u_rows (G, Ha)``.  Groups
-    without a tile_map in a sparse slot ride along dense (all-ones bitmap).
+    without a tile_map in a sparse slot ride along dense (all-ones
+    bitmap).
     Per-(item, layer, direction) transforms memoize in ``memo`` (one walk's)
     so the chunk slots of one layer quantize/compact the weights ONCE per
     walk — under a ``PlanProgram``, once per trace.
@@ -497,7 +500,7 @@ def _slot_weights(slot, params, live, memo: dict):
         if entry is None:
             U = _cell_layer_params(params, live[cell.uid], cell)["U"] \
                 .reshape(slot.H, gates, slot.H)
-            if slot.precision == "bf16":
+            if slot.precision == "bf16" and U.dtype == jnp.float32:
                 U = bf16_roundtrip(U)
             s = None
             if quant:
@@ -724,6 +727,25 @@ def _cat_pad(rows, B: int):
         return cat
     pad = [(0, B - cat.shape[0])] + [(0, 0)] * (cat.ndim - 1)
     return jnp.pad(cat, pad)
+
+
+def stage_weights(params: dict) -> dict:
+    """``params`` with each layer's (and direction's) input and recurrent
+    matrices W and U cast to bfloat16: the values a one-pass bfloat16 MXU
+    dot rounds them to.  U feeds the sequence kernel's bfloat16 dot
+    (``kernels.common.mxu_bf16_operands``), W the hoisted input GEMM,
+    whose program would otherwise convert it on every call; b stays
+    float32.  ``rnn.CompiledStack`` stages a stack once and passes the
+    result to its forward and prefill programs, so no call casts a
+    weight; at that precision every other reader (an external schedule's
+    ``x @ W`` and ``h @ U``) rounds its operands to the same values."""
+    def half(p):
+        return dict(p, W=p["W"].astype(jnp.bfloat16),
+                    U=p["U"].astype(jnp.bfloat16))
+
+    return dict(params, layers=[
+        dict(l, fwd=half(l["fwd"]), bwd=half(l["bwd"])) if "fwd" in l
+        else half(l) for l in params["layers"]])
 
 
 def prepare_decode_stack(stack_params: dict, family: str,

@@ -9,6 +9,26 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def mxu_bf16_operands(interpret: bool) -> bool:
+    """Whether a kernel's float32 dot runs as one bfloat16 MXU pass with
+    float32 accumulation: compiled for the TPU (not interpreted) at the
+    caller's default matmul precision.  Mosaic lowers such a dot in the
+    MXU's f32 mode, which rounds both operands to bfloat16, so bfloat16
+    operands give the same products at half the width in the MXU's
+    latches.  The CPU's interpreted dot is exact float32, and a caller's
+    ``jax.default_matmul_precision("highest")`` asks for float32
+    contraction: neither runs one bfloat16 pass."""
+    if interpret:
+        return False
+    level = jax.config.jax_default_matmul_precision
+    if level is None:
+        return True
+    try:
+        return jax.lax.Precision(level) == jax.lax.Precision.DEFAULT
+    except ValueError:      # a dot algorithm preset, not a precision level
+        return False
+
+
 def cdiv(a: int, b: int) -> int:
     return -(-a // b)
 

@@ -33,7 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cdiv, seq_vmem_limit_of
+from repro.kernels.common import (cdiv, mxu_bf16_operands,
+                                  seq_vmem_limit_of)
 
 
 def _kernel(h_ref, u_ref, xw_ref, c_ref, h_out_ref, c_out_ref, acc_ref, *,
@@ -111,7 +112,8 @@ def lstm_cell_pallas(U4, xw_t, h_prev, c_prev, *, block_h: int, block_k: int,
 
 
 def _seq_kernel(*refs, block_t: int, T: int, masked: bool,
-                quant: bool = False, sparse: bool = False):
+                quant: bool = False, sparse: bool = False,
+                bf16_dot: bool = False):
     """One grid step = one T-block of one recurrence ``g``.
 
     Grid is (G, n_t) with t innermost; (h, c) persist in VMEM scratch across
@@ -141,6 +143,12 @@ def _seq_kernel(*refs, block_t: int, T: int, masked: bool,
     (1, Ha) int32 row-index operand; h is gathered to the surviving rows
     before the dot.  Padding rows are zero U rows at index 0 — exact
     no-ops (see kernels.quant.compact_rows).
+
+    ``bf16_dot``: U arrives bfloat16 and the dot runs as one bfloat16 MXU
+    pass (``kernels.common.mxu_bf16_operands``): U feeds the MXU as it
+    is and h is cast to bfloat16 before each dot — the values the MXU's
+    f32 mode rounds its operands to, latched at half the width.  The
+    accumulate, the gates and (h, c) stay float32.
     """
     refs = list(refs)
     xw_ref, u_ref = refs[:2]
@@ -162,10 +170,12 @@ def _seq_kernel(*refs, block_t: int, T: int, masked: bool,
         h_scr[...] = h0_ref[0].astype(jnp.float32)
         c_scr[...] = c0_ref[0].astype(jnp.float32)
 
-    # (Hr, 4H) — resident across the walk; upcast ONCE per grid step,
-    # outside the t loop (the dot runs f32 x f32 on every weight dtype;
-    # the int8 per-gate scale rides on the accumulate below)
-    U = u_ref[0].astype(jnp.float32)
+    # (Hr, 4H) — resident across the walk.  A bf16 dot reads its block
+    # at each step (a U value held across the t loop costs the compiler a
+    # VMEM copy of it, which the VMEM model does not count); every other
+    # dot reads the f32 upcast, made ONCE per grid step outside the loop
+    # (the int8 per-gate scale rides on the accumulate below)
+    U = None if bf16_dot else u_ref[0].astype(jnp.float32)
     H = hs_ref.shape[-1]
     base = t * block_t
     row_ok = None if m_ref is None else m_ref[0] != 0        # (B, 1)
@@ -173,8 +183,9 @@ def _seq_kernel(*refs, block_t: int, T: int, masked: bool,
     def step(i, carry):
         h, c = carry
         h_in = h if not sparse else jnp.take(h, rows_ref[0, 0], axis=1)
+        u = u_ref[0] if bf16_dot else U
         acc = jax.lax.dot_general(
-            h_in, U, (((1,), (0,)), ((), ())),
+            h_in.astype(u.dtype), u, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)              # (B, 4H)
         if quant:
             acc = acc * s_ref[0]
@@ -219,7 +230,10 @@ def lstm_seq_pallas(U4, xw, h0, c0, *, block_t: int, interpret: bool = True,
     common width (ragged-B packing): zero rows are exact no-ops.
 
     ``u_scales`` (G,4) f32: U4 is int8 per-gate quantized; fp32
-    accumulate, scale applied post-dot (see kernels.quant).  ``u_rows``
+    accumulate, scale applied post-dot (see kernels.quant).  A bfloat16
+    U4 feeds the MXU bfloat16 operands where the dot runs as one
+    bfloat16 pass (``kernels.common.mxu_bf16_operands``); elsewhere the
+    kernel upcasts it, as it does every other weight dtype.  ``u_rows``
     (G,Ha) int32: U4 is row-compacted to (G,Ha,4,H) — the kernel gathers
     h to the surviving rows (block-sparse row tiles).
 
@@ -236,8 +250,9 @@ def lstm_seq_pallas(U4, xw, h0, c0, *, block_t: int, interpret: bool = True,
     masked = b_mask is not None
     quant = u_scales is not None
     sparse = u_rows is not None
+    bf16_dot = U4.dtype == jnp.bfloat16 and mxu_bf16_operands(interpret)
     kernel = functools.partial(_seq_kernel, block_t=bt, T=T, masked=masked,
-                               quant=quant, sparse=sparse)
+                               quant=quant, sparse=sparse, bf16_dot=bf16_dot)
     xw_tm = jnp.swapaxes(xw.reshape(G, B, T, 4 * H), 1, 2)   # (G,T,B,4H)
     in_specs = [
         pl.BlockSpec((1, bt, B, 4 * H), lambda g, t: (g, t, 0, 0)),  # xw

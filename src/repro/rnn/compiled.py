@@ -45,7 +45,9 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.schedules import stack_families
 from repro.dispatch import (DispatchPlan, PlanProgram, WorkItem, execute,
-                            plan, plan_decode, prepare_decode_stack)
+                            plan, plan_decode, prepare_decode_stack,
+                            stage_weights)
+from repro.kernels.common import default_interpret, mxu_bf16_operands
 from repro.rnn.policy import ExecutionPolicy
 from repro.runtime.errors import ExecutionReport, FaultInjector
 from repro.runtime.obs import NULL_TRACER, Tracer
@@ -77,9 +79,10 @@ class StackStats:
 
     ``u_bytes_staged`` counts the recurrent-weight bytes the calls'
     launches brought into VMEM (``DispatchPlan.u_bytes_staged``: every g
-    of every slot stages its cell's U, 4 bytes a weight), so a window's
-    delta over its calls and over the stack's U bytes is how many times
-    each layer's U was loaded per call.
+    of every slot stages its cell's U, counted at 4 bytes a weight even
+    where the stack staged U in bfloat16), so a window's delta over its
+    calls and over the stack's float32 U bytes is how many times each
+    layer's U was loaded per call.
 
     ``measured_hits``/``analytic_fallbacks`` (policy
     ``cost_model="measured"``) count the measured cost model's lookup
@@ -258,6 +261,7 @@ class CompiledStack:
         self._last_plan: Optional[DispatchPlan] = None
         self._plans: Dict[tuple, PlanProgram] = {}  # each plan's program
         self._prepared: Optional[dict] = None
+        self._staged: Optional[dict] = None   # stage_weights(params)
 
     # ------------------------------------------------------------------
     @property
@@ -280,6 +284,27 @@ class CompiledStack:
                         share=0, families=self.families,
                         precision=self.policy.precision,
                         tile_map=self._tile_map)
+
+    def _seq_params(self) -> dict:
+        """The parameters the forward and prefill programs bind.  Where
+        the sequence kernel feeds the MXU bfloat16 operands — a dense LSTM
+        stack whose weights are not int8, compiled for the TPU, at the
+        caller's default matmul precision
+        (``kernels.common.mxu_bf16_operands``, read at every call, since
+        the precision is the caller's) — the stack with each W and U cast
+        to bfloat16 once (``dispatch.stage_weights``); a program receives
+        it as an argument, so no call casts a weight.  Otherwise, and for
+        decode, the stack's own parameters."""
+        pol = self.policy
+        interpret = default_interpret() if pol.interpret is None \
+            else pol.interpret
+        if not (set(self.families) == {"lstm"} and pol.precision != "int8"
+                and pol.sparsity != "block"
+                and mxu_bf16_operands(interpret)):
+            return self.params
+        if self._staged is None:
+            self._staged = stage_weights(self.params)
+        return self._staged
 
     @property
     def _dir_key(self) -> str:
@@ -409,7 +434,7 @@ class CompiledStack:
             if T == 0:
                 raise ValueError("CompiledStack.forward: T=0 sequence")
             entry = self._lower_many(((B, T, str(xs.dtype)),), (0,))
-            outs = self._execute(entry, {0: self.params}, {0: xs})
+            outs = self._execute(entry, {0: self._seq_params()}, {0: xs})
             if tr.enabled:
                 p = entry.plan
                 sp.tag(B=B, T=T, plan=tr.plan_id(p), launches=p.launches)
@@ -461,8 +486,9 @@ class CompiledStack:
             entry = self._lower_many(
                 tuple((x.shape[0], x.shape[1], str(x.dtype))
                       for x in inputs.values()), tuple(prios))
+            params = self._seq_params()
             outs, states = self._execute(
-                entry, {i: self.params for i in inputs}, inputs,
+                entry, {i: params for i in inputs}, inputs,
                 collect_state=True)
             if tr.enabled:
                 sp.tag(plan=tr.plan_id(entry.plan),

@@ -12,6 +12,7 @@ process may hold the TPU compiler library, and every test worker imports
 this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -138,31 +139,49 @@ def test_decode_kernel_compiles(one_chip, family, dtypes):
     _compile(lambda *a: op(*a, interpret=False), *args)
 
 
-def test_eesen_plan_program_compiles(one_chip):
-    """The whole EESEN plan program — every slot's hoist, pack, launch and
-    scatter as one jitted program — for bidirectional H340 L5 at B64 in
-    float32.  T60 has the slot structure of the benchmark's T300 (8-step
-    stripes, a ragged remainder, interleaved fwd/bwd waves) with fewer
-    chunks, so the compile stays short."""
+def _stack_shapes(sh, Hw, X, L, bidirectional):
+    """A float32 stack's parameter shapes, placed on the described chip."""
+    def s(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sh)
+
+    def half(x):
+        return {"W": s((x, 4 * Hw)), "U": s((Hw, 4 * Hw)), "b": s((4 * Hw,))}
+
+    if bidirectional:
+        return {"layers": [{"fwd": half(X if l == 0 else 2 * Hw),
+                            "bwd": half(X if l == 0 else 2 * Hw)}
+                           for l in range(L)]}
+    return {"layers": [half(X if l == 0 else Hw) for l in range(L)]}
+
+
+def _plan_program(sh, params, B, T, X, bound=None):
+    """A compiled stack's plan at (B, T), and the plan's program lowered
+    over ``bound`` (default ``params``: the float32 stack)."""
     from repro import rnn
     from repro.dispatch import PlanProgram
 
-    B, T, X, L = 64, 60, 120, 5
-
-    def s(shape):
-        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
-
-    def half(x):
-        return {"W": s((x, 4 * H)), "U": s((H, 4 * H)), "b": s((4 * H,))}
-
-    params = {"layers": [{"fwd": half(X if l == 0 else 2 * H),
-                          "bwd": half(X if l == 0 else 2 * H)}
-                         for l in range(L)]}
     plan = rnn.compile(params, rnn.ExecutionPolicy(interpret=False)) \
         .lower(B, T)
-    compiled = PlanProgram(plan, interpret=False).lower(
-        {0: params}, {0: s((B, T, X))}).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    lowered = PlanProgram(plan, interpret=False).lower(
+        {0: params if bound is None else bound},
+        {0: jax.ShapeDtypeStruct((B, T, X), jnp.float32, sharding=sh)})
+    return plan, lowered
+
+
+def test_eesen_plan_program_compiles(one_chip):
+    """The whole EESEN plan program — every slot's hoist, pack, launch and
+    scatter as one jitted program — for bidirectional H340 L5 at B64 in
+    float32, at the default precision and under a caller's ``highest``
+    (where a compiled stack binds its float32 U).  T60 has the slot
+    structure of the benchmark's T300 (8-step stripes, a ragged remainder,
+    interleaved fwd/bwd waves) with fewer chunks, so the compile stays
+    short."""
+    B, T, X, L = 64, 60, 120, 5
+    params = _stack_shapes(one_chip, H, X, L, True)
+    for level in ("default", "highest"):
+        with jax.default_matmul_precision(level):
+            _, lowered = _plan_program(one_chip, params, B, T, X)
+            assert "tpu_custom_call" in lowered.compile().as_text()
 
 
 @pytest.mark.parametrize("G", [1, 2])
@@ -190,20 +209,77 @@ def test_h1024_seq_kernel_compiles(one_chip, family, G):
 def test_gmat_plan_program_compiles(one_chip):
     """The whole GMAT plan program — 17 unidirectional H1024 layers at B64
     in float32, every hoisted input GEMM and lstm_seq launch — at T16, a
-    chunk of 16 frames per layer, so the compile stays short."""
-    from repro import rnn
-    from repro.dispatch import PlanProgram
-
+    chunk of 16 frames per layer, so the compile stays short; at the
+    default precision and under a caller's ``highest``."""
     B, T, Hw, L = 64, 16, 1024, 17
+    params = _stack_shapes(one_chip, Hw, Hw, L, False)
+    for level in ("default", "highest"):
+        with jax.default_matmul_precision(level):
+            plan, lowered = _plan_program(one_chip, params, B, T, Hw)
+            assert plan.launches == L
+            assert lowered.compile().as_text().count("tpu_custom_call") \
+                >= L
 
-    def s(shape):
-        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
 
-    params = {"layers": [{"W": s((Hw, 4 * Hw)), "U": s((Hw, 4 * Hw)),
-                          "b": s((4 * Hw,))} for _ in range(L)]}
-    plan = rnn.compile(params, rnn.ExecutionPolicy(interpret=False)) \
-        .lower(B, T)
-    assert plan.launches == L
-    compiled = PlanProgram(plan, interpret=False).lower(
-        {0: params}, {0: s((B, T, Hw))}).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= L
+@pytest.mark.parametrize("G", [1, 2])
+def test_h1024_staged_seq_kernel_compiles(one_chip, G):
+    """GMAT's width with U staged in bfloat16, at the stripe the planner
+    picks (it budgets U at 4 bytes a weight, so 19 frames as for float32
+    U), at G=1 (GMAT's plan) and G=2."""
+    Hw, B, T = 1024, 64, 75
+    bt = select_time_block(T, B, Hw)
+    assert bt == 19
+
+    def s(shape, dt="float32"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    _compile(_seq_fn("lstm", bt), s((G, Hw, 4, Hw), "bfloat16"),
+             s((G, B, T, 4, Hw)), s((G, B, Hw)), s((G, B, Hw)))
+
+
+#: a convert to bfloat16 in the program as traced (StableHLO), before
+#: XLA's own passes add theirs (such as the hoist GEMM's operands)
+_STAGING = re.compile(r"stablehlo\.convert.*-> tensor<[^>]*bf16>")
+#: a convert to bfloat16 in the compiled program, with its result's shape
+_CAST = re.compile(r"= bf16\[([0-9,]*)\]\S* convert\(")
+
+
+@pytest.mark.parametrize("cell", ["eesen", "gmat"])
+def test_benchmark_plan_programs_take_staged_u(one_chip, cell):
+    """The benchmark cells' whole plan programs at their published shapes
+    (EESEN B64 x T300, GMAT B64 x T75) compile over the stack a compiled
+    stack binds at the default precision (``dispatch.stage_weights``):
+    every W and U argument is bfloat16, no call casts one (neither the
+    traced nor the compiled program converts to bfloat16 an array of a
+    weight's shape), and the plans keep their launches — 200 and 17 — and
+    GMAT its 19-frame stripe."""
+    from repro.dispatch import stage_weights
+
+    Hw, X, L, T, bidir, launches = {
+        "eesen": (H, 120, 5, 300, True, 200),
+        "gmat": (1024, 1024, 17, 75, False, 17)}[cell]
+    B = 64
+    params = _stack_shapes(one_chip, Hw, X, L, bidir)
+    staged = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(stage_weights, params))
+    plan, lowered = _plan_program(one_chip, params, B, T, X, bound=staged)
+    assert plan.launches == launches
+    if cell == "gmat":
+        assert {s.stripe for s in plan.slots} == {19}
+    weights = {}
+    for path, a in jax.tree_util.tree_leaves_with_path(lowered.args_info):
+        if path[-1] in (jax.tree_util.DictKey("W"), jax.tree_util.DictKey("U")):
+            weights.setdefault(path[-1].key, []).append(a)
+    assert {k: len(v) for k, v in weights.items()} == {
+        "W": L * (2 if bidir else 1), "U": L * (2 if bidir else 1)}
+    assert {str(a.dtype) for v in weights.values() for a in v} == \
+        {"bfloat16"}
+    assert {a.shape for a in weights["U"]} == {(Hw, 4 * Hw)}
+    assert not _STAGING.search(lowered.as_text())
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") >= launches
+    shapes = {tuple(a.shape) for v in weights.values() for a in v}
+    casts = {tuple(int(d) for d in m.split(",") if d)
+             for m in _CAST.findall(text)}
+    assert not {tuple(d for d in c if d != 1) for c in casts} & shapes
